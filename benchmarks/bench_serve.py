@@ -250,8 +250,14 @@ def _profiled_rows(n, max_new):
     row["derived"] += (f" sigs={s['signatures']} "
                        f"prof_disp={s['dispatches']} "
                        f"compiles={dec.get('compiles', 0)} "
-                       f"util={st.decode_util:.2e}")
+                       f"util={_util(st.decode_util)}")
     return [row]
+
+
+def _util(u) -> str:
+    """A utilization for the derived column; None (no peaks for this
+    device) reads "not measured"."""
+    return "not measured" if u is None else f"{u:.2e}"
 
 
 def _tenant_rows():

@@ -29,12 +29,15 @@ import sys
 import time
 import traceback
 
-# Force a multi-device host platform BEFORE any benchmark module imports jax,
-# so bench_serve's sharded-continuous rows measure a real (4, 2) mesh instead
-# of a degenerate single-device one. No-op if jax is already imported or the
-# flag is already set (REPRO_BENCH_DEVICES overrides the count).
-from repro.launch._bootstrap import force_host_devices
+# CPU-only test path: force a multi-device host platform BEFORE any
+# benchmark module imports jax, so bench_serve's sharded-continuous rows
+# run a real (4, 2) mesh on the CPU instead of a degenerate single-device
+# one. No-op if jax is already imported or the flag is already set
+# (REPRO_BENCH_DEVICES overrides the count); on a TPU host it changes
+# nothing.
+from repro.launch._bootstrap import force_host_devices, use_compile_cache
 
+use_compile_cache()
 force_host_devices(os.environ.get("REPRO_BENCH_DEVICES", "8"))
 
 MODULES = [

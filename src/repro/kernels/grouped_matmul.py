@@ -9,7 +9,8 @@ the flash-attention state carry.
 ``valid_rows`` (tokens actually routed to each expert, <= capacity) lets the
 kernel skip fully-empty row blocks — the TPU analogue of megablocks' ragged
 GEMM: instead of CUDA block-sparse tiles we prune whole grid steps with
-pl.when, which the sequential grid makes free.
+pl.when, which the sequential grid makes free. It is a scalar-prefetch
+operand (SMEM): Mosaic refuses a rank-1 ``(1,)`` VMEM block for it.
 """
 from __future__ import annotations
 
@@ -29,8 +30,7 @@ def _kernel(valid_ref, x_ref, w_ref, o_ref, acc_ref, *, bm: int, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    valid = valid_ref[0]
-    run = mi * bm < valid               # any valid row in this block?
+    run = mi * bm < valid_ref[pl.program_id(0)]   # any valid row here?
 
     @pl.when(run)
     def _body():
@@ -57,16 +57,20 @@ def grouped_matmul(x, w, valid_rows=None, *, bm: int = 128, bn: int = 128,
     nk = k // bk
 
     kernel = functools.partial(_kernel, bm=bm, nk=nk)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(g, c // bm, n // bn, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda gi, mi, ni, ki: (gi,)),
-            pl.BlockSpec((1, bm, bk), lambda gi, mi, ni, ki: (gi, mi, ki)),
-            pl.BlockSpec((1, bk, bn), lambda gi, mi, ni, ki: (gi, ki, ni)),
+            pl.BlockSpec((1, bm, bk), lambda gi, mi, ni, ki, vr: (gi, mi, ki)),
+            pl.BlockSpec((1, bk, bn), lambda gi, mi, ni, ki, vr: (gi, ki, ni)),
         ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda gi, mi, ni, ki: (gi, mi, ni)),
-        out_shape=jax.ShapeDtypeStruct((g, c, n), x.dtype),
+        out_specs=pl.BlockSpec((1, bm, bn),
+                               lambda gi, mi, ni, ki, vr: (gi, mi, ni)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((g, c, n), x.dtype),
         interpret=interpret,
-    )(valid_rows, x, w)
+    )(jnp.asarray(valid_rows, jnp.int32), x, w)

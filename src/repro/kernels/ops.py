@@ -3,7 +3,8 @@
 Each wrapper handles layout (head flattening, padding to block multiples),
 dtype promotion, and backend selection: on CPU the kernels execute in
 ``interpret=True`` mode (Python emulation of the kernel body — the
-correctness path used by CI); on TPU they compile to Mosaic.
+correctness path used by CI); on TPU they compile to Mosaic; any other
+backend raises.
 """
 from __future__ import annotations
 
@@ -19,7 +20,17 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret on the CPU (the correctness path of the tests), compile
+    to Mosaic on a TPU. Any other backend is refused rather than silently
+    interpreted, which would time the interpreter as if it were the
+    kernel."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas TPU kernels cannot run on backend "
+                       f"{backend!r}; use cpu (interpret) or tpu")
 
 
 def _pad_to(x, axis: int, mult: int):
@@ -62,7 +73,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 @jax.jit
 def paged_attention(q, k_pages, v_pages, tables, pos, window=0):
-    """q: [B, Hq, D]; k_pages, v_pages: [NB, BS, Hkv, D]; tables: [B, MB]
+    """q: [B, Hq, D]; k_pages, v_pages: [NB, Hkv, BS, D]; tables: [B, MB]
     int32 block ids (-1 = unassigned); pos: [B] int32; window: int32 scalar
     (0 = full attention; dynamic — gemma3's per-layer windows are traced).
     Returns [B, Hq, D]. Q heads are grouped per kv head (head h -> kv h//g,
@@ -70,7 +81,7 @@ def paged_attention(q, k_pages, v_pages, tables, pos, window=0):
     repetition in HBM.
     """
     b, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     g = hq // hkv
     qg = q.reshape(b, hkv, g, d)
     win = jnp.asarray(window, jnp.int32).reshape(1)
@@ -85,7 +96,7 @@ def paged_attention(q, k_pages, v_pages, tables, pos, window=0):
 def paged_prefill_attention(q, k_pages, v_pages, tables, start, window=0):
     """q: [B, C, Hq, D] — one C-token prefill chunk per slot, row b's query
     c at logical position ``start[b] + c``; k_pages, v_pages:
-    [NB, BS, Hkv, D]; tables: [B, MB] int32 block ids (-1 = unassigned);
+    [NB, Hkv, BS, D]; tables: [B, MB] int32 block ids (-1 = unassigned);
     start: [B] int32; window: int32 scalar (0 = full; dynamic — gemma3's
     per-layer windows are traced). Returns [B, C, Hq, D]. The chunk's own
     K/V must already be written through the table (the layer writes before
@@ -93,7 +104,7 @@ def paged_prefill_attention(q, k_pages, v_pages, tables, start, window=0):
     heads group per kv head as in ``paged_attention``.
     """
     b, c, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     g = hq // hkv
     qg = q.reshape(b, c, hkv, g, d).transpose(0, 2, 1, 3, 4)
     win = jnp.asarray(window, jnp.int32).reshape(1)
@@ -106,18 +117,26 @@ def paged_prefill_attention(q, k_pages, v_pages, tables, start, window=0):
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(xdt, a_log, B, C, *, chunk: int = 128):
-    """xdt: [B, S, H, P]; a_log: [B, S, H]; B, C: [B, S, H, N]."""
+    """xdt: [B, S, H, P]; a_log: [B, S, H]; B, C: [B, S, H, N].
+
+    Any S: the sequence is zero-padded at its end to a multiple of the
+    chunk (a prompt shorter than ``chunk`` uses one chunk of S rounded up
+    to the 8-row sublane tile). Causality keeps the padding out of every
+    real position, and Mosaic needs the chunk rows tile-aligned."""
     b, s, h, p = xdt.shape
     n = B.shape[-1]
-    xf = xdt.transpose(0, 2, 1, 3).reshape(b * h, s, p).astype(jnp.float32)
-    af = a_log.transpose(0, 2, 1).reshape(b * h, s, 1).astype(jnp.float32)
-    bf = B.transpose(0, 2, 1, 3).reshape(b * h, s, n).astype(jnp.float32)
-    cf = C.transpose(0, 2, 1, 3).reshape(b * h, s, n).astype(jnp.float32)
-    q = chunk
-    while s % q != 0:
-        q //= 2
-    y = _ssd.ssd_scan_bhsp(xf, af, bf, cf, chunk=q, interpret=_interpret())
-    return y.reshape(b, h, s, p).transpose(0, 2, 1, 3)
+    q = min(chunk, -(-s // 8) * 8)
+    sp = -(-s // q) * q
+
+    def heads_major(x, width):
+        x = x.reshape(b, s, h, width).transpose(0, 2, 1, 3)
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, sp - s), (0, 0)))
+        return x.reshape(b * h, sp, width).astype(jnp.float32)
+
+    y = _ssd.ssd_scan_bhsp(heads_major(xdt, p), heads_major(a_log, 1),
+                           heads_major(B, n), heads_major(C, n), chunk=q,
+                           interpret=_interpret())
+    return y.reshape(b, h, sp, p)[:, :, :s].transpose(0, 2, 1, 3)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))

@@ -17,6 +17,12 @@ table to DMA only the blocks the slot actually owns — unassigned entries
 ``pl.when`` (online softmax over valid blocks only). GQA costs nothing extra:
 the q-head group of each kv head rides along as the block's row dimension.
 
+The pool is head-major, ``[NB, Hkv, BS, D]``, so one (block, kv head) tile
+is the whole trailing ``(BS, D)`` slab. Mosaic requires the last two dims
+of a block to be full or divisible by the (8, 128) tiling; a token-major
+``[NB, BS, Hkv, D]`` pool would need a ``(BS, 1, D)`` block, whose size-1
+head dim breaks that rule.
+
 Two kernels share the scheme: the decode kernel (one query token per slot)
 and the prefill kernel (a C-token chunk per slot at contiguous positions,
 causal masking inside the chunk) — the latter is what lane-batched chunked
@@ -55,8 +61,8 @@ def _kernel(tables_ref, pos_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(run)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)              # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)           # [BS, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)              # [BS, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (g, bs), 1)
@@ -114,8 +120,8 @@ def _prefill_kernel(tables_ref, start_ref, win_ref, q_ref, k_ref, v_ref,
     @pl.when(run)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32).reshape(c * g, -1)   # [CG, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)                   # [BS, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                      # [BS, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (c * g, bs),
@@ -154,7 +160,7 @@ def _prefill_kernel(tables_ref, start_ref, win_ref, q_ref, k_ref, v_ref,
 def paged_prefill_bkgd(q, k_pages, v_pages, tables, start, window, *,
                        interpret: bool = True):
     """q: [B, Hkv, C, G, D] (a C-token prefill chunk per slot, q heads
-    grouped per kv head); k_pages, v_pages: [NB, BS, Hkv, D]; tables:
+    grouped per kv head); k_pages, v_pages: [NB, Hkv, BS, D]; tables:
     [B, MB] int32 (-1 = unassigned); start: [B] int32 — row b's chunk
     covers contiguous logical positions [start[b], start[b] + C); window:
     [1] int32 (0 = full attention). The chunk's K/V must already be written
@@ -163,7 +169,7 @@ def paged_prefill_bkgd(q, k_pages, v_pages, tables, start, window, *,
     block. Returns [B, Hkv, C, G, D].
     """
     b, hkv, c, g, d = q.shape
-    nb, bs = k_pages.shape[:2]
+    bs = k_pages.shape[2]
     mb = tables.shape[1]
     scale = 1.0 / math.sqrt(d)
 
@@ -175,12 +181,12 @@ def paged_prefill_bkgd(q, k_pages, v_pages, tables, start, window, *,
         in_specs=[
             pl.BlockSpec((1, 1, c, g, d),
                          lambda i, h, j, tables, start, win: (i, h, 0, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
+            pl.BlockSpec((1, 1, bs, d),
                          lambda i, h, j, tables, start, win:
-                         (jnp.maximum(tables[i, j], 0), 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, d),
+                         (jnp.maximum(tables[i, j], 0), h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, d),
                          lambda i, h, j, tables, start, win:
-                         (jnp.maximum(tables[i, j], 0), 0, h, 0)),
+                         (jnp.maximum(tables[i, j], 0), h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, c, g, d),
                                lambda i, h, j, tables, start, win:
@@ -202,11 +208,11 @@ def paged_prefill_bkgd(q, k_pages, v_pages, tables, start, window, *,
 def paged_attention_bkgd(q, k_pages, v_pages, tables, pos, window, *,
                          interpret: bool = True):
     """q: [B, Hkv, G, D] (q heads grouped per kv head); k_pages, v_pages:
-    [NB, BS, Hkv, D]; tables: [B, MB] int32 (-1 = unassigned); pos: [B]
+    [NB, Hkv, BS, D]; tables: [B, MB] int32 (-1 = unassigned); pos: [B]
     int32; window: [1] int32 (0 = full attention). Returns [B, Hkv, G, D].
     """
     b, hkv, g, d = q.shape
-    nb, bs = k_pages.shape[:2]
+    bs = k_pages.shape[2]
     mb = tables.shape[1]
     scale = 1.0 / math.sqrt(d)
 
@@ -217,12 +223,12 @@ def paged_attention_bkgd(q, k_pages, v_pages, tables, pos, window, *,
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
                          lambda i, h, j, tables, pos, win: (i, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
+            pl.BlockSpec((1, 1, bs, d),
                          lambda i, h, j, tables, pos, win:
-                         (jnp.maximum(tables[i, j], 0), 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, d),
+                         (jnp.maximum(tables[i, j], 0), h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, d),
                          lambda i, h, j, tables, pos, win:
-                         (jnp.maximum(tables[i, j], 0), 0, h, 0)),
+                         (jnp.maximum(tables[i, j], 0), h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
                                lambda i, h, j, tables, pos, win: (i, h, 0, 0)),

@@ -29,18 +29,28 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0):
     return out.reshape(b, sq, hq, d).astype(q.dtype)
 
 
+def _gather_pages(pages, tables):
+    """Head-major pages [NB, Hkv, BS, D] through tables [B, MB] -> f32
+    [B, MB * BS, Hkv, D] in logical position order (-1 entries read block
+    0; callers mask them)."""
+    b = tables.shape[0]
+    _, hkv, _, d = pages.shape
+    g = pages[jnp.maximum(tables, 0)]                  # [B, MB, Hkv, BS, D]
+    return g.transpose(0, 1, 3, 2, 4).reshape(b, -1, hkv, d).astype(
+        jnp.float32)
+
+
 def paged_attention(q, k_pages, v_pages, tables, pos, window=0):
     """Paged decode attention oracle (one query token per slot).
 
-    q: [B, Hq, D]; k_pages, v_pages: [NB, BS, Hkv, D]; tables: [B, MB] int32
+    q: [B, Hq, D]; k_pages, v_pages: [NB, Hkv, BS, D]; tables: [B, MB] int32
     block ids (-1 = unassigned); pos: [B] int32 — row b attends logical
     positions [0, pos[b]] gathered through its block table. -> [B, Hq, D].
     """
-    nb, bs, hkv, d = k_pages.shape
+    nb, hkv, bs, d = k_pages.shape
     b, hq, _ = q.shape
-    safe = jnp.maximum(tables, 0)
-    k = k_pages[safe].reshape(b, -1, hkv, d).astype(jnp.float32)
-    v = v_pages[safe].reshape(b, -1, hkv, d).astype(jnp.float32)
+    k = _gather_pages(k_pages, tables)
+    v = _gather_pages(v_pages, tables)
     k_pos = jnp.arange(k.shape[1])[None, :]
     valid = jnp.repeat(tables >= 0, bs, axis=1) & (k_pos <= pos[:, None])
     if window:
@@ -57,17 +67,16 @@ def paged_attention(q, k_pages, v_pages, tables, pos, window=0):
 def paged_prefill_attention(q, k_pages, v_pages, tables, start, window=0):
     """Paged prefill-chunk attention oracle (C query tokens per slot).
 
-    q: [B, C, Hq, D]; k_pages, v_pages: [NB, BS, Hkv, D]; tables: [B, MB]
+    q: [B, C, Hq, D]; k_pages, v_pages: [NB, Hkv, BS, D]; tables: [B, MB]
     int32 block ids (-1 = unassigned); start: [B] int32 — row b's query c
     sits at logical position ``start[b] + c`` and attends positions
     [0, start[b] + c] gathered through its block table (causal inside the
     chunk). -> [B, C, Hq, D].
     """
-    nb, bs, hkv, d = k_pages.shape
+    nb, hkv, bs, d = k_pages.shape
     b, c, hq, _ = q.shape
-    safe = jnp.maximum(tables, 0)
-    k = k_pages[safe].reshape(b, -1, hkv, d).astype(jnp.float32)
-    v = v_pages[safe].reshape(b, -1, hkv, d).astype(jnp.float32)
+    k = _gather_pages(k_pages, tables)
+    v = _gather_pages(v_pages, tables)
     k_pos = jnp.arange(k.shape[1])[None, None, :]                # [1, 1, K]
     q_pos = (start[:, None] + jnp.arange(c)[None, :])[:, :, None]  # [B, C, 1]
     valid = jnp.repeat(tables >= 0, bs, axis=1)[:, None, :] & (k_pos <= q_pos)

@@ -10,7 +10,9 @@ kernel's warp-level state exchange. Per chunk (length Q):
     inter:  Y += (C * exp(lc)) S_prev    (read carried state)
     state:  S  = gamma * S_prev + (B * w)^T X
 
-All math in f32; block shapes (Q x N), (Q x P) are MXU-aligned for Q,N,P in
+All math in f32: every matmul runs at ``Precision.HIGHEST``, since the MXU
+would otherwise round the f32 operands (decay-weighted scores, carried
+state) to bf16. Block shapes (Q x N), (Q x P) are MXU-aligned for Q,N,P in
 {64,128,256}.
 """
 from __future__ import annotations
@@ -21,6 +23,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+_F32 = dict(preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
 
 
 def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, q: int):
@@ -35,31 +41,35 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, q: int):
     b = b_ref[0]                       # [Q, N]
     c = c_ref[0]                       # [Q, N]
 
-    lc = jnp.cumsum(a)                 # within-chunk cumulative log decay
+    idx = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    jdx = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    causal = idx >= jdx
+    # within-chunk cumulative log decay, lc_i = sum_{j<=i} a_j, as a masked
+    # lower-triangular row sum: Mosaic has no cumsum lowering, and an f32
+    # reduction on the vector unit keeps full precision where a matmul on
+    # the MXU would round the decays through bf16 passes.
+    lc = jnp.sum(jnp.where(causal, a[None, :], 0.0), axis=1)
     l_last = lc[q - 1]
 
     # intra-chunk dual form
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)  # [Q,Q]
+                                 **_F32)                              # [Q,Q]
     diff = lc[:, None] - lc[None, :]
     decay = jnp.exp(jnp.minimum(diff, 0.0))
-    idx = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-    jdx = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    m = jnp.where(idx >= jdx, scores * decay, 0.0)
-    y = jax.lax.dot_general(m, x, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)       # [Q,P]
+    m = jnp.where(causal, scores * decay, 0.0)
+    y = jax.lax.dot_general(m, x, (((1,), (0,)), ((), ())), **_F32)  # [Q,P]
 
     # inter-chunk contribution from carried state
     c_in = c * jnp.exp(lc)[:, None]
     y += jax.lax.dot_general(c_in, state_ref[...], (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+                             **_F32)
 
     # state update: S = gamma * S_prev + sum_j w_j B_j x_j^T
     w = jnp.exp(l_last - lc)                                           # [Q]
     bw = b * w[:, None]
     state_ref[...] = (jnp.exp(l_last) * state_ref[...]
                       + jax.lax.dot_general(bw, x, (((0,), (0,)), ((), ())),
-                                            preferred_element_type=jnp.float32))
+                                            **_F32))
     y_ref[0] = y.astype(y_ref.dtype)
 
 

@@ -31,9 +31,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import INPUT_SHAPES, get_config
 from repro.dist import sharding as shd
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-                               axis_sizes, make_host_mesh,
-                               make_production_mesh)
+from repro.launch.mesh import (CHIP_PEAKS, TARGET_KIND, axis_sizes,
+                               make_host_mesh, make_production_mesh)
 from repro.models.api import build_model, cache_specs, input_specs, params_specs
 from repro.train import state as state_lib
 from repro.train.optimizer import adamw, constant
@@ -92,9 +91,9 @@ def cache_pspecs(cfg, cache_shape, mesh, *, seq_shard: bool, batch: int,
     phi3.5-moe kv=8 at 32k x batch 128 do not fit HBM otherwise); decode
     attention handles a seq-sharded KV via partial-softmax all-reduce.
 
-    ``paged=True`` describes the block-pool layout instead: k/v leaves are
-    ``[L, n_blocks, block_size, kv, hd]`` — the block dimension stays
-    unsharded (any slot's table may name any block, so blocks must be
+    ``paged=True`` describes the head-major block-pool layout instead: k/v
+    leaves are ``[L, n_blocks, kv, block_size, hd]`` — the block dimension
+    stays unsharded (any slot's table may name any block, so blocks must be
     addressable without a gather collective), KV heads shard over 'model',
     and the small-KV-head fallback shards the in-block position dimension."""
     ba = _batch_axes(mesh)
@@ -110,10 +109,10 @@ def cache_pspecs(cfg, cache_shape, mesh, *, seq_shard: bool, batch: int,
         def m_ax(dim):
             return "model" if _div(shape[dim], msize) else None
         if paged and name in ("k", "v"):
-            # [L, NB, BS, kv, hd]
-            s_ax = ("model" if m_ax(3) is None and _div(shape[2], msize)
+            # [L, NB, kv, BS, hd]
+            s_ax = ("model" if m_ax(2) is None and _div(shape[3], msize)
                     else None)
-            return P(None, None, s_ax, m_ax(3), None)
+            return P(None, None, m_ax(2), s_ax, None)
         if name in ("k", "v") or name.endswith(("attn_k", "attn_v")):
             # [L_or_G, B, S, kv, hd]
             if seq_shard:
@@ -328,8 +327,6 @@ def lower_combo(arch: str, shape_name: str, multi_pod: bool,
 
     n_chips = mesh.devices.size
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):       # older jax: one dict per program
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_stats = {
@@ -361,9 +358,12 @@ def lower_combo(arch: str, shape_name: str, multi_pod: bool,
         bytes_accessed = probe_stats["bytes_per_chip"]
         wire = probe_stats["wire_bytes_per_chip"]
 
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = bytes_accessed / HBM_BW
-    collective_s = wire / ICI_BW
+    # the roofline of the chip the production meshes describe, not of the
+    # host this compile ran on
+    peaks = CHIP_PEAKS[TARGET_KIND]
+    compute_s = flops / peaks["flops_bf16"]
+    memory_s = bytes_accessed / peaks["hbm_bw"]
+    collective_s = wire / peaks["ici_bw"]
 
     n = get_config(arch).param_count()
     n_active = get_config(arch).param_count(active_only=True)

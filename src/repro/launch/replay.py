@@ -22,8 +22,12 @@ schedule file via ``--faults-file`` (see ``FaultSchedule.to_json``).
 import os
 import sys
 
-from repro.launch._bootstrap import force_host_devices, mesh_flag
+from repro.launch._bootstrap import (force_host_devices, mesh_flag,
+                                    use_compile_cache)
 
+use_compile_cache()
+# CPU-only test path: --mesh host on the CPU backend needs forced host
+# devices; on a TPU host the flag changes nothing.
 if mesh_flag(sys.argv) == "host":
     force_host_devices(os.environ.get("REPRO_SERVE_DEVICES", "8"))
 

@@ -298,12 +298,13 @@ def _print_human(report: dict) -> None:
     if report["phase_costs"]:
         print("\nphase costs:")
         print(f"  {'phase':<14} {'count':>5} {'total ms':>9} {'mean ms':>8} "
-              f"{'compiles':>8} {'util':>6}")
+              f"{'compiles':>8} {'util':>12}")
         for row in report["phase_costs"]:
-            util = f"{row['util']:.3g}" if row["util"] is not None else "—"
+            util = (f"{row['util']:.3g}" if row["util"] is not None
+                    else "not measured")
             print(f"  {row['phase']:<14} {row['count']:>5} "
                   f"{row['total_ms']:>9.1f} {row['mean_ms']:>8.2f} "
-                  f"{row['compiles']:>8} {util:>6}")
+                  f"{row['compiles']:>8} {util:>12}")
     print("\noccupancy shares (step-weighted):")
     for t, s in report["occupancy_shares"].items():
         print(f"  {t:<10} {s['share']*100:5.1f}%  "

@@ -266,7 +266,9 @@ DECODE_BACKENDS = ("contiguous", "paged")
 class PagedKV(NamedTuple):
     """One layer's paged decode cache: block-pool K/V plus the block table.
 
-    k, v: [n_blocks, block_size, Hkv, D] — the shared block pool.
+    k, v: [n_blocks, Hkv, block_size, D] — the shared block pool, head-major
+    so a (block, kv head) tile is one contiguous [block_size, D] slab (the
+    block shape the Pallas paged kernels can DMA on a TPU).
     tables: [B, max_blocks] int32 — row b's logical position p lives in block
     ``tables[b, p // block_size]`` at offset ``p % block_size``; -1 marks an
     unassigned table column (padding rows read nothing and write nowhere).
@@ -304,7 +306,7 @@ def paged_kv_write(pkv: PagedKV, k, v, positions, valid=None) -> PagedKV:
     batched prefill chunk (a short final chunk padded to block_size must not
     scatter garbage into its own — or, prefix-shared, anyone else's —
     blocks)."""
-    nb, bs = pkv.k.shape[:2]
+    nb, _, bs, _ = pkv.k.shape
     mb = pkv.tables.shape[1]
     p = jnp.asarray(positions, jnp.int32)
     col = jnp.clip(p // bs, 0, mb - 1)           # pad positions may overrun
@@ -313,8 +315,10 @@ def paged_kv_write(pkv: PagedKV, k, v, positions, valid=None) -> PagedKV:
     if valid is not None:
         blk = jnp.where(valid, blk, nb)
     off = p % bs
-    nk = pkv.k.at[blk, off].set(k.astype(pkv.k.dtype), mode="drop")
-    nv = pkv.v.at[blk, off].set(v.astype(pkv.v.dtype), mode="drop")
+    # the two [B, C] index arrays straddle the head slice, so the indexed
+    # update is laid out [B, C, Hkv, D] — k/v's own layout
+    nk = pkv.k.at[blk, :, off].set(k.astype(pkv.k.dtype), mode="drop")
+    nv = pkv.v.at[blk, :, off].set(v.astype(pkv.v.dtype), mode="drop")
     return PagedKV(nk, nv, pkv.tables)
 
 
@@ -322,11 +326,12 @@ def paged_kv_gather(pkv: PagedKV):
     """Materialize each row's pages: -> (k [B, MB*BS, Hkv, D], v likewise,
     k_pos [B, MB*BS] logical positions, valid [B, MB*BS] assigned-block
     mask). Unassigned table entries gather block 0 and are masked off."""
-    nb, bs = pkv.k.shape[:2]
+    _, hkv, bs, d = pkv.k.shape
     b, mb = pkv.tables.shape
     safe = jnp.maximum(pkv.tables, 0)
-    kg = pkv.k[safe].reshape(b, mb * bs, *pkv.k.shape[2:])
-    vg = pkv.v[safe].reshape(b, mb * bs, *pkv.v.shape[2:])
+    # [B, MB, Hkv, BS, D] -> position-major [B, MB * BS, Hkv, D]
+    kg = pkv.k[safe].transpose(0, 1, 3, 2, 4).reshape(b, mb * bs, hkv, d)
+    vg = pkv.v[safe].transpose(0, 1, 3, 2, 4).reshape(b, mb * bs, hkv, d)
     k_pos = jnp.broadcast_to(jnp.arange(mb * bs, dtype=jnp.int32)[None],
                              (b, mb * bs))
     valid = jnp.repeat(pkv.tables >= 0, bs, axis=1)
@@ -433,7 +438,7 @@ def attention(p, cfg, x, positions, *, causal: bool = True,
     """
     b, s, _ = x.shape
     if isinstance(kv_cache, PagedKV):
-        kv_len = kv_cache.tables.shape[1] * kv_cache.k.shape[1]
+        kv_len = kv_cache.tables.shape[1] * kv_cache.k.shape[2]
     else:
         kv_len = (kv_cache[0].shape[1] if kv_cache is not None
                   else cross_kv[0].shape[1] if cross_kv is not None else s)

@@ -99,6 +99,11 @@ def _split_xbc(cfg, xBC):
     return x, B, C
 
 
+#: the SSD math is f32 (log decays, carried state); on a TPU the MXU would
+#: round f32 einsum operands to bf16 at the default precision
+_F32 = jax.lax.Precision.HIGHEST
+
+
 def ssd_chunked(xdt, a_log, B, C, chunk: int = 256):
     """Chunked SSD scan (pure-jnp reference path used by the model).
 
@@ -118,7 +123,7 @@ def ssd_chunked(xdt, a_log, B, C, chunk: int = 256):
     l_last = lc[:, :, -1:, :]                        # total chunk decay
 
     # intra-chunk (dual/attention form)
-    scores = jnp.einsum("bcihn,bcjhn->bchij", Cm, Bm)
+    scores = jnp.einsum("bcihn,bcjhn->bchij", Cm, Bm, precision=_F32)
     li = lc.transpose(0, 1, 3, 2)                    # [b,nc,h,q]
     # valid (j <= i) exponents are <= 0; clamp the masked ones to avoid
     # inf * 0 -> NaN in the backward pass of the where().
@@ -127,11 +132,12 @@ def ssd_chunked(xdt, a_log, B, C, chunk: int = 256):
     idx = jnp.arange(q)
     mask = idx[:, None] >= idx[None, :]
     m = jnp.where(mask, scores * decay, 0.0)
-    y_intra = jnp.einsum("bchij,bcjhp->bcihp", m, xdt)
+    y_intra = jnp.einsum("bchij,bcjhp->bcihp", m, xdt, precision=_F32)
 
     # chunk states: S_c = sum_j exp(l_last - l_j) B_j (x) xdt_j
     w = jnp.exp(l_last - lc)                         # [b,nc,q,h]
-    states = jnp.einsum("bcjhn,bcjh,bcjhp->bchnp", Bm, w, xdt)
+    states = jnp.einsum("bcjhn,bcjh,bcjhp->bchnp", Bm, w, xdt,
+                        precision=_F32)
 
     # inter-chunk recurrence: T_c = gamma_c * T_{c-1} + S_c
     gamma = jnp.exp(l_last[:, :, 0, :])              # [b,nc,h]
@@ -147,7 +153,8 @@ def ssd_chunked(xdt, a_log, B, C, chunk: int = 256):
                            (gamma.swapaxes(0, 1), states.swapaxes(0, 1)))
     t_in = t_in.swapaxes(0, 1)                       # [b,nc,h,n,p]
 
-    y_inter = jnp.einsum("bcihn,bcih,bchnp->bcihp", Cm, jnp.exp(lc), t_in)
+    y_inter = jnp.einsum("bcihn,bcih,bchnp->bcihp", Cm, jnp.exp(lc), t_in,
+                         precision=_F32)
     y = (y_intra + y_inter).reshape(b, s, h, p)
     return y
 
@@ -162,11 +169,25 @@ def _best_chunk(s: int) -> int:
 # ---------------------------------------------------------------------------
 # block forward
 # ---------------------------------------------------------------------------
-def block_fwd(cfg, p, x):
-    """x: [B, S, D] -> [B, S, D] (pre-norm residual applied by caller)."""
+def ssd_final_state(xdt, a_log, B):
+    """The recurrent state after the last position of an SSD pass:
+    h_S = sum_j exp(sum_{i>j} a_i) B_j (x) xdt_j -> [B, H, N, P] — what
+    ``S`` decode steps from a zero state would carry.
+
+    xdt: [B, S, H, P]; a_log: [B, S, H]; B: [B, S, H, N]."""
+    later = jnp.cumsum(a_log[:, ::-1], axis=1)[:, ::-1] - a_log
+    return jnp.einsum("bshn,bsh,bshp->bhnp", B, jnp.exp(later), xdt,
+                      precision=_F32)
+
+
+def block_fwd(cfg, p, x, return_state: bool = False):
+    """x: [B, S, D] -> [B, S, D] (pre-norm residual applied by caller).
+    ``return_state`` also returns the decode state after the last position,
+    (conv [B, K-1, conv_ch], ssm [B, H, N, P]), as ``block_decode`` keeps
+    it."""
     di, g, n, h, ph, conv_ch = _dims(cfg)
-    z, xBC, dt = _project(cfg, p, x)
-    xBC = jax.nn.silu(causal_conv1d(xBC, p["conv_w"], p["conv_b"]))
+    z, xBC_in, dt = _project(cfg, p, x)
+    xBC = jax.nn.silu(causal_conv1d(xBC_in, p["conv_w"], p["conv_b"]))
     xs, B, C = _split_xbc(cfg, xBC)
 
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
@@ -186,7 +207,12 @@ def block_fwd(cfg, p, x):
     y = shard(y, "batch", None, "inner_flat")
 
     y = L.rmsnorm(y * jax.nn.silu(z), p["gate_norm"], cfg.norm_eps)
-    return y @ p["out_proj"]
+    out = y @ p["out_proj"]
+    if not return_state:
+        return out
+    k = cfg.ssm_conv - 1
+    conv = jnp.pad(xBC_in, ((0, 0), (k, 0), (0, 0)))[:, -k:]
+    return out, (conv, ssd_final_state(xdt, a_log, B.astype(jnp.float32)))
 
 
 def block_decode(cfg, p, x, conv_state, ssm_state):
@@ -236,6 +262,24 @@ def forward(cfg, params, tokens):
     x, _ = L.scan_layers(cfg, body, x, params["layers"])
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return L.unembed(params["emb"], cfg, x)
+
+
+def prefill(cfg, params, tokens):
+    """Prompt pass for serving: the chunked SSD forward (the Pallas
+    ``ssd_scan`` kernel under ``cfg.use_pallas``) over ``tokens`` [B, S]
+    -> (last-position logits [B, 1, V], the decode cache ``init_cache``
+    describes, holding the state after the last prompt token)."""
+    x = L.embed(params["emb"], cfg, tokens)
+
+    def body(x, p):
+        h = L.rmsnorm(x, p["norm"], cfg.norm_eps)
+        out, state = block_fwd(cfg, p, h, return_state=True)
+        return shard(x + out, "batch", None, None), state
+
+    x, (conv, ssm) = L.scan_layers(cfg, body, x, params["layers"])
+    x = L.rmsnorm(x[:, -1:], params["final_norm"], cfg.norm_eps)
+    return (L.unembed(params["emb"], cfg, x),
+            {"conv": conv.astype(jnp.dtype(cfg.dtype)), "ssm": ssm})
 
 
 def loss_fn(cfg, params, batch):

@@ -71,7 +71,7 @@ def _attention_dyn_window(cfg, p, x, positions, window, kv_cache, cache_pos,
     """Attention with a *traced* window size (for scanned local/global mix)."""
     b, s, _ = x.shape
     if isinstance(kv_cache, L.PagedKV):
-        kv_len = kv_cache.tables.shape[1] * kv_cache.k.shape[1]
+        kv_len = kv_cache.tables.shape[1] * kv_cache.k.shape[2]
     else:
         kv_len = kv_cache[0].shape[1] if kv_cache is not None else s
     scheme = L.plan_attention_scheme(cfg, b, s, kv_len)
@@ -291,10 +291,12 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None):
 def init_paged_cache(cfg, n_blocks: int, block_size: int, dtype=None):
     """Block-pool decode cache: ``n_blocks`` blocks of ``block_size`` KV
     positions shared by all requests (serve/paged.py's BlockManager carves
-    them up); the per-request block tables live outside the pytree."""
+    them up); the per-request block tables live outside the pytree. Leaves
+    are head-major ``[L, n_blocks, Hkv, block_size, D]`` (see
+    ``layers.PagedKV``)."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     hd, nkv = cfg.resolved_head_dim, cfg.n_kv_heads
-    shape = (cfg.n_layers, n_blocks, block_size, nkv, hd)
+    shape = (cfg.n_layers, n_blocks, nkv, block_size, hd)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 

@@ -18,8 +18,10 @@ per-dispatch wall time with:
   * **an analytic roofline term per signature** — FLOPs and HBM bytes
     computed from the config shapes (the same model-FLOPs convention
     ``launch/dryrun.py`` records: 2·N_active·tokens, plus per-position KV
-    traffic), against the TPU-v5e peaks ``launch/mesh.py`` publishes — so
-    every execute dispatch gets a measured-vs-roofline utilization ratio.
+    traffic), against the peaks of the device the run is on
+    (``launch.mesh.CHIP_PEAKS``) — so every execute dispatch on a known
+    chip gets a measured-vs-roofline utilization ratio. A device missing
+    from the table (the CPU among them) gets ``util=None``: not measured.
   * **per-tenant cost shares** — dispatch seconds split by lane/slot
     occupancy (a decode horizon whose bucket holds 3 rows of tenant A and
     1 of tenant B charges A 75% of the dispatch).
@@ -49,7 +51,7 @@ truthiness check (``if prof: ...``), the same contract as ``NULL_TRACER``.
 This module stays jax/numpy-free (like the rest of ``repro.obs``) so
 ``trace_report`` and store tooling run anywhere the files land; the
 roofline peaks are resolved lazily from ``launch.mesh`` when a real
-profiler is built, with the v5e constants as the import-free fallback.
+profiler is built without explicit ones.
 """
 from __future__ import annotations
 
@@ -58,11 +60,6 @@ import os
 import time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
-
-#: fallback roofline peaks (TPU v5e, per chip) — mirrors ``launch.mesh``;
-#: ``DispatchProfiler`` prefers the live import so the numbers cannot drift.
-_PEAK_FLOPS_BF16 = 197e12
-_HBM_BW = 819e9
 
 _ACT_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "float64": 8}
 
@@ -107,7 +104,9 @@ class DispatchProfiler:
     model reads; without one the profiler still measures and attributes
     compile-vs-execute but reports no roofline terms. ``n_devices`` splits
     the analytic terms per chip for sharded engines (SPMD divides the work;
-    the measured wall time is already per-program).
+    the measured wall time is already per-program). ``peak_flops`` /
+    ``hbm_bw`` default to the table entry of the device jax runs on; with
+    neither given nor found, utilization is None.
     """
     enabled = True
 
@@ -115,17 +114,15 @@ class DispatchProfiler:
                  peak_flops: Optional[float] = None,
                  hbm_bw: Optional[float] = None):
         if peak_flops is None or hbm_bw is None:
-            try:        # live peaks (needs jax); fallback mirrors them
-                from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
-                peak_flops = peak_flops or PEAK_FLOPS_BF16
-                hbm_bw = hbm_bw or HBM_BW
-            except Exception:
-                peak_flops = peak_flops or _PEAK_FLOPS_BF16
-                hbm_bw = hbm_bw or _HBM_BW
+            from repro.launch.mesh import chip_peaks
+            peaks = chip_peaks() or {}
+            peak_flops = peak_flops or peaks.get("flops_bf16")
+            hbm_bw = hbm_bw or peaks.get("hbm_bw")
         self.cfg = cfg
         self.n_devices = max(int(n_devices), 1)
-        self.peak_flops = float(peak_flops)
-        self.hbm_bw = float(hbm_bw)
+        #: None when the device has no table entry: no roofline figure
+        self.peak_flops = float(peak_flops) if peak_flops else None
+        self.hbm_bw = float(hbm_bw) if hbm_bw else None
         self.records: List[dict] = []
         self.tenant_s: Dict[str, float] = {}
         self._seen: set = set()
@@ -200,10 +197,11 @@ class DispatchProfiler:
         self._seen.add(sig)
         flops, hbm = self.roofline_terms(phase, tokens=tokens, k=k,
                                          kv_pos_sum=kv_pos_sum)
-        roof_s = max(flops / self.peak_flops, hbm / self.hbm_bw) \
-            / self.n_devices
-        util = (roof_s / dur_s) if (not first and dur_s > 0 and roof_s > 0) \
-            else None
+        util = None
+        if self.peak_flops and self.hbm_bw and not first and dur_s > 0:
+            roof_s = max(flops / self.peak_flops, hbm / self.hbm_bw) \
+                / self.n_devices
+            util = (roof_s / dur_s) if roof_s > 0 else None
         rec = {"phase": phase, "sig": sig, "dur_s": float(dur_s),
                "compile": first, "tokens": tokens, "width": int(width),
                "k": int(k), "flops": flops, "hbm_bytes": hbm,

@@ -19,8 +19,9 @@ join/evict per step. TP/DP-sharded decode is the same loop again with a
 ``sharded.ServeSharding`` plan installed (see serve/sharded.py).
 
 Prefill (contiguous): attention-family models (dense / vlm / moe) run ONE
-full forward pass capturing the per-layer K/V via ``return_cache``;
-recurrent families (ssm / hybrid / encdec) scan decode steps. Prefill is
+full forward pass capturing the per-layer K/V via ``return_cache``; ssm
+(mamba2) runs ONE chunked-SSD pass that also returns the recurrent state;
+the other recurrent families (hybrid / encdec) scan decode steps. Prefill is
 per-request at the exact prompt length — no cross-request padding — so a
 request's output never depends on what it was batched with, which is what
 makes continuous and static batching produce identical per-request outputs.
@@ -181,9 +182,11 @@ class ServeStats:
     max_queue_depth: int = 0
     mean_occupancy: float = 0.0       # pool occupancy at horizon boundaries
     max_occupancy: float = 0.0        # (paged: used blocks; contig: slots)
-    # -- dispatch profiling (obs.prof; 0.0 with profiling off) -----------------
-    decode_util: float = 0.0          # mean measured-vs-roofline utilization
-                                      # over execute decode dispatches
+    # -- dispatch profiling (obs.prof; None with profiling off) ----------------
+    decode_util: Optional[float] = None  # mean measured-vs-roofline
+                                      # utilization over execute decode
+                                      # dispatches; None = not measured (no
+                                      # profiler, or a device without peaks)
     # -- fault injection (serve/chaos.py; all 0 without an injector) -----------
     faults_injected: int = 0          # faults applied at horizon boundaries
     recoveries: int = 0               # recovery actions taken (regenerate /
@@ -356,7 +359,8 @@ class ServeEngine:
                  allocation: Optional[TenantAllocation] = None,
                  tracer=None, metrics_every: int = 1, profiler=None,
                  injector=None, max_admit_retries: int = 4,
-                 elastic=None, profile_store=None):
+                 elastic=None, profile_store=None,
+                 record_logits: bool = False):
         if cache not in CACHE_BACKENDS:
             raise ValueError(f"unknown cache backend {cache!r}; "
                              f"known: {CACHE_BACKENDS}")
@@ -414,6 +418,9 @@ class ServeEngine:
         #: allocator's knee model tracks measurement instead of analytic
         #: constants (ROADMAP item 1's first slice).
         self.profile_store = profile_store
+        #: keep each request's prompt logits on ``prefill_logits`` — one
+        #: extra [vocab] fetch per prefill, for checks against a reference
+        self.record_logits = bool(record_logits)
         #: the allocation as constructed — reshapes re-plan in place, so
         #: ``run`` restores this before every run to keep warm runs
         #: identical.
@@ -465,6 +472,13 @@ class ServeEngine:
                 widths = ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))
                 return logits[:, -1:], {"k": jnp.pad(k, widths),
                                         "v": jnp.pad(v, widths)}
+            return prefill
+
+        if cfg.family == "ssm":
+            def prefill(params, tokens):
+                """Chunked-SSD prefill: one pass over the prompt that
+                seeds the recurrent state (mamba2.prefill)."""
+                return model.module.prefill(cfg, params, tokens)
             return prefill
 
         def prefill(params, tokens):
@@ -803,7 +817,8 @@ class ServeEngine:
             max_queue_depth=int(qd_max),
             mean_occupancy=occ_mean,
             max_occupancy=occ_max,
-            decode_util=m.series_stats("util[decode]")[0],
+            decode_util=(m.series_stats("util[decode]")[0]
+                         if "util[decode]" in m.gauges else None),
             scale_ups=int(m.value("scale_ups")),
             scale_downs=int(m.value("scale_downs")),
             migrated_blocks=int(m.value("migrated_blocks")),
@@ -1390,6 +1405,9 @@ class ServeEngine:
                 tok = int(self._select_tokens(logits[:, -1], [r.slot],
                                               ~sched.step, c)[0])
                 r.output.append(tok)
+                if self.record_logits:
+                    r.prefill_logits = np.asarray(
+                        logits[0, -1].astype(jnp.float32))
                 if self.eos_token is not None and tok == self.eos_token:
                     r.finished_early = True
                 if tr or prof:
@@ -1551,6 +1569,9 @@ class ServeEngine:
                     logits[np.asarray(done_idx), -1], slots, ~step, c)
                 for t, i in zip(toks, done_idx):
                     lanes[i].req.output.append(int(t))
+                    if self.record_logits:
+                        lanes[i].req.prefill_logits = np.asarray(
+                            logits[i, -1].astype(jnp.float32))
             lanes = live
 
     def _growth_blocks_needed(self, sched, pool: BlockManager, pos_np,

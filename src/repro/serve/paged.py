@@ -3,10 +3,11 @@
 The serving mirror of Synergy's memory-sensitivity argument (PAPER.md §4):
 `CachePool` gives every request a full ``max_len`` cache row — the
 GPU-proportional over-allocation the paper argues against. ``BlockManager``
-instead carves one ``[n_blocks, block_size, ...]`` buffer per cache leaf into
-fixed-size blocks: a request at length L holds exactly ``ceil(L /
-block_size)`` blocks behind a per-request block table, so a 40-token prompt
-in a 256-position pool costs 3 blocks of 16 instead of a 256-row.
+instead carves one head-major ``[n_blocks, Hkv, block_size, D]`` buffer per
+layer's K and V into fixed-size blocks: a request at length L holds exactly
+``ceil(L / block_size)`` blocks behind a per-request block table, so a
+40-token prompt in a 256-position pool costs 3 blocks of 16 instead of a
+256-row.
 
 Admission is watermark-based: a request is admitted when its *prompt* blocks
 fit while keeping ``watermark * n_blocks`` blocks free as decode-growth

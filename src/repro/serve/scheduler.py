@@ -70,6 +70,9 @@ class ServeRequest:
     t_arrived: Optional[float] = None
     t_admitted: Optional[float] = None
     t_finished: Optional[float] = None
+    #: the prompt's last-position logits [vocab] (f32), kept only by an
+    #: engine built with ``record_logits=True`` (a correctness check's hook)
+    prefill_logits: Optional[np.ndarray] = None
 
     @property
     def done(self) -> bool:

@@ -69,8 +69,8 @@ def test_paged_attention_kernel_vs_ref(hq, hkv, window):
     pure-jnp paged oracle, across GQA group sizes and sliding windows."""
     ks = jax.random.split(jax.random.key(hq * 31 + hkv + window), 3)
     nb, bs, d, b, mb = 10, 8, 32, 3, 4
-    kp = _rand(ks[0], (nb, bs, hkv, d))
-    vp = _rand(ks[1], (nb, bs, hkv, d))
+    kp = _rand(ks[0], (nb, hkv, bs, d))
+    vp = _rand(ks[1], (nb, hkv, bs, d))
     q = _rand(ks[2], (b, hq, d))
     tables = jnp.array([[3, 7, -1, -1], [0, 1, 2, 9], [5, -1, -1, -1]],
                        jnp.int32)
@@ -91,8 +91,10 @@ def test_paged_attention_matches_contiguous_layout():
     vc = _rand(ks[1], (1, mb * bs, h, d))
     q = _rand(ks[2], (1, h, d))
     perm = jnp.array([5, 0, 3, 7], jnp.int32)        # scattered block homes
-    kp = jnp.zeros((8, bs, h, d)).at[perm].set(kc[0].reshape(mb, bs, h, d))
-    vp = jnp.zeros((8, bs, h, d)).at[perm].set(vc[0].reshape(mb, bs, h, d))
+    kp = jnp.zeros((8, h, bs, d)).at[perm].set(
+        kc[0].reshape(mb, bs, h, d).transpose(0, 2, 1, 3))
+    vp = jnp.zeros((8, h, bs, d)).at[perm].set(
+        vc[0].reshape(mb, bs, h, d).transpose(0, 2, 1, 3))
     out = ops.paged_attention(q, kp, vp, perm[None], jnp.array([s], jnp.int32))
     logits = jnp.einsum("bhd,bkhd->bhk", q, kc) / np.sqrt(d)
     logits = jnp.where(jnp.arange(mb * bs)[None, None] <= s, logits, -1e30)
@@ -108,8 +110,8 @@ def test_paged_attention_property_rowsum(seed):
     matter how the blocks are scattered or how much padding the table has."""
     ks = jax.random.split(jax.random.key(seed), 2)
     nb, bs, h, d, b = 6, 4, 2, 16, 2
-    kp = _rand(ks[0], (nb, bs, h, d))
-    vp = jnp.ones((nb, bs, h, d))
+    kp = _rand(ks[0], (nb, h, bs, d))
+    vp = jnp.ones((nb, h, bs, d))
     q = _rand(ks[1], (b, 2 * h, d))
     tables = jnp.array([[2, 4, -1], [1, -1, -1]], jnp.int32)
     pos = jnp.array([6, 1], jnp.int32)
@@ -120,7 +122,8 @@ def test_paged_attention_property_rowsum(seed):
 # ---------------------------------------------------------------------------
 # ssd scan
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("s,chunk", [(64, 16), (128, 32), (96, 32), (256, 128)])
+@pytest.mark.parametrize("s,chunk", [(64, 16), (128, 32), (96, 32), (256, 128),
+                                     (100, 32), (20, 64)])
 @pytest.mark.parametrize("n,p", [(16, 32), (64, 64)])
 def test_ssd_scan_sweep(s, chunk, n, p):
     ks = jax.random.split(jax.random.key(s + n), 4)
@@ -239,8 +242,8 @@ def test_paged_prefill_kernel_vs_ref(hq, hkv, window):
     pure-jnp paged prefill oracle, across GQA group sizes and windows."""
     ks = jax.random.split(jax.random.key(hq * 37 + hkv + window), 3)
     nb, bs, d, b, mb, c = 10, 8, 32, 3, 4, 6
-    kp = _rand(ks[0], (nb, bs, hkv, d))
-    vp = _rand(ks[1], (nb, bs, hkv, d))
+    kp = _rand(ks[0], (nb, hkv, bs, d))
+    vp = _rand(ks[1], (nb, hkv, bs, d))
     q = _rand(ks[2], (b, c, hq, d))
     tables = jnp.array([[3, 7, -1, -1], [0, 1, 2, 9], [5, 6, -1, -1]],
                        jnp.int32)
@@ -256,8 +259,8 @@ def test_paged_prefill_kernel_causal_inside_chunk():
     chunk's first query row equals single-token decode at that position."""
     ks = jax.random.split(jax.random.key(5), 3)
     nb, bs, h, d, c = 6, 4, 2, 16, 4
-    kp = _rand(ks[0], (nb, bs, h, d))
-    vp = _rand(ks[1], (nb, bs, h, d))
+    kp = _rand(ks[0], (nb, h, bs, d))
+    vp = _rand(ks[1], (nb, h, bs, d))
     q = _rand(ks[2], (1, c, h, d))
     tables = jnp.array([[2, 0, -1]], jnp.int32)
     start = jnp.array([4], jnp.int32)

@@ -150,8 +150,9 @@ def test_chunked_prefill_matches_one_pass(arch):
                                np.asarray(full_logits[0, -1]),
                                atol=2e-4, rtol=2e-4)
     # the paged cache holds the same K/V at every valid logical position
-    paged_k = np.asarray(cache["k"])[:, tables[0, :nblk]]       # [L,NB,BS,..]
-    paged_k = paged_k.reshape(cfg.n_layers, 1, nblk * bs, *paged_k.shape[3:])
+    paged_k = np.asarray(cache["k"])[:, tables[0, :nblk]]    # [L,NB,H,BS,D]
+    paged_k = paged_k.transpose(0, 1, 3, 2, 4).reshape(
+        cfg.n_layers, 1, nblk * bs, cfg.n_kv_heads, -1)
     np.testing.assert_allclose(paged_k[:, :, :s],
                                np.asarray(k_full), atol=1e-5, rtol=1e-5)
 
@@ -209,6 +210,28 @@ def test_paged_matches_contiguous_static_per_request(arch):
     # steps * n_slots would have
     assert stats.decode_rows_saved > 0.0
     assert stats.block_report["block_size"] == 4
+
+
+def test_recorded_prompt_logits_match_across_backends():
+    """``record_logits`` keeps each request's own prompt logits: the paged
+    engine's lane-batched chunks hand every request the logits of its last
+    prompt position, equal to the contiguous one-pass prefill's, and the
+    first greedy token is their argmax."""
+    cfg = get_config("qwen2-0.5b", smoke=True)
+    lengths, arrivals = [9, 3, 14, 6], [0.0, 0.0, 1.0, 2.0]
+    contig, _ = ServeEngine(cfg, max_len=32, record_logits=True).run(
+        _requests(cfg, lengths, max_new=2))
+    paged, _ = ServeEngine(cfg, max_len=32, n_slots=2, cache="paged",
+                           block_size=4, prefill_lanes=2,
+                           record_logits=True).run(
+        _requests(cfg, lengths, arrivals, max_new=2))
+    for a, b in zip(contig, paged):
+        assert b.prefill_logits.shape == (cfg.vocab_size,)
+        np.testing.assert_allclose(b.prefill_logits, a.prefill_logits,
+                                   atol=2e-4, rtol=2e-4)
+        assert b.output[0] == int(np.argmax(b.prefill_logits))
+    assert ServeEngine(cfg, max_len=32).run(
+        _requests(cfg, lengths))[0][0].prefill_logits is None
 
 
 def test_paged_block_reuse_never_leaks_prior_kv():

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import CHIP_PEAKS
 from repro.launch.roofline import fmt_row
 from repro.launch.trace_report import build_report, phase_costs
 from repro.obs import (NULL_PROFILER, DispatchProfiler, NullDispatchProfiler,
@@ -16,6 +17,12 @@ from repro.obs import (NULL_PROFILER, DispatchProfiler, NullDispatchProfiler,
                        validate_events)
 from repro.serve import ServeEngine, ServeRequest
 from repro.serve.tenant import profile_class
+
+
+#: explicit peaks for the utilization tests: off a chip the profiler finds
+#: none, and reports no utilization
+V5E = CHIP_PEAKS["TPU v5 lite"]
+PEAKS = dict(peak_flops=V5E["flops_bf16"], hbm_bw=V5E["hbm_bw"])
 
 
 def _requests(cfg, lengths, max_new=4, arrivals=None, tenants=None, seed=11):
@@ -65,7 +72,7 @@ def test_compile_attribution_per_signature():
 
 def test_roofline_terms_nonzero_and_util_gauge():
     cfg = get_config("qwen2-0.5b", smoke=True)
-    prof = DispatchProfiler(cfg)
+    prof = DispatchProfiler(cfg, **PEAKS)
     flops, hbm = prof.roofline_terms("decode", tokens=32, k=8, kv_pos_sum=100)
     assert flops > 0 and hbm > 0
     # decode re-reads the weights every scan step: k scales the byte term
@@ -78,6 +85,20 @@ def test_roofline_terms_nonzero_and_util_gauge():
     assert obs.metrics.gauge("util[decode]").value == pytest.approx(rec["util"])
     assert obs.value("compile_s[decode]") == pytest.approx(0.5)
     assert obs.value("execute_s[decode]") == pytest.approx(0.02)
+
+
+def test_device_without_peaks_reports_no_utilization():
+    """Off the peaks table (the CPU here) there is no roofline figure: no
+    chip's peaks stand in for the device the run is on."""
+    from repro.launch.mesh import chip_peaks
+    assert chip_peaks("cpu") is None
+    prof = DispatchProfiler(get_config("qwen2-0.5b", smoke=True))
+    assert prof.peak_flops is None and prof.hbm_bw is None
+    obs = RunObs()
+    prof.record("decode", 0.5, width=4, k=8, obs=obs)          # compile
+    rec = prof.record("decode", 0.02, width=4, k=8, obs=obs)   # execute
+    assert rec["flops"] > 0 and rec["util"] is None
+    assert "util[decode]" not in obs.metrics.gauges
 
 
 def test_tenant_cost_shares_split_by_rows():
@@ -121,7 +142,7 @@ def test_profiled_run_emits_compile_split_and_util():
     """A warm second run on the same engine yields execute records with
     nonzero utilization, surfaced as the decode_util stat."""
     cfg = get_config("qwen2-0.5b", smoke=True)
-    prof = DispatchProfiler(cfg)
+    prof = DispatchProfiler(cfg, **PEAKS)
     eng = ServeEngine(cfg, max_len=24, n_slots=2, cache="paged",
                       block_size=4, profiler=prof)
     eng.run(_requests(cfg, [5, 7]))

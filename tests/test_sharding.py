@@ -11,6 +11,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist import sharding as shd
+from repro.launch.mesh import make_host_mesh, make_mesh
 
 needs_mesh = pytest.mark.skipif(
     jax.device_count() < 8,
@@ -18,7 +19,7 @@ needs_mesh = pytest.mark.skipif(
 
 
 def host_mesh():
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return make_host_mesh(model_axis=2)
 
 
 # ---------------------------------------------------------------------------
@@ -33,7 +34,7 @@ def test_off_mesh_everything_is_noop():
 
 
 def test_rules_pop_on_exit_and_nest():
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     with shd.axis_rules(mesh, {"batch": "data"}) as outer:
         assert shd.current_rules() is outer
         with shd.axis_rules(mesh, {"batch": None}) as inner:

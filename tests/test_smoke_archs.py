@@ -92,3 +92,29 @@ def test_decode_matches_forward_prefix_ssm():
     for t in range(8):
         logits, cache = step(params, cache, toks[:, t:t + 1], jnp.int32(t))
         assert jnp.allclose(logits[0, 0], full[0, t], atol=2e-3), f"pos {t}"
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_ssm_prefill_state_matches_decode_steps(use_pallas):
+    """The serve prefill's one chunked-SSD pass (the Pallas kernel or the
+    jnp path) seeds the same decode state and last logits as scanning the
+    prompt through decode steps, at a length that is not a chunk multiple."""
+    from repro.models import mamba2
+    cfg = get_config("mamba2-780m", smoke=True, use_pallas=use_pallas)
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    s = 45
+    toks = jax.random.randint(jax.random.key(5), (2, s), 0, cfg.vocab_size)
+    logits, cache = jax.jit(lambda p, t: mamba2.prefill(cfg, p, t))(params,
+                                                                    toks)
+
+    want = model.init_cache(2, s)
+    step = jax.jit(model.decode_step)
+    for t in range(s):
+        last, want = step(params, want, toks[:, t:t + 1], jnp.int32(t))
+    assert logits.shape == last.shape
+    assert jnp.allclose(logits, last, atol=2e-3)
+    for name in ("conv", "ssm"):
+        assert cache[name].shape == want[name].shape
+        assert cache[name].dtype == want[name].dtype
+        assert jnp.allclose(cache[name], want[name], atol=2e-4), name
