@@ -1,0 +1,9 @@
+"""Device: share of the traced window in which no operation ran on the
+device (1 - union of device op intervals / window)."""
+
+
+def read(rec):
+    trace = rec["trace"]
+    if trace is None:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
