@@ -117,4 +117,5 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -73,4 +73,5 @@ def grouped_matmul(x, w, valid_rows=None, *, bm: int = 128, bn: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, c, n), x.dtype),
         interpret=interpret,
+        name="grouped_matmul",
     )(jnp.asarray(valid_rows, jnp.int32), x, w)

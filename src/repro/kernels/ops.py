@@ -5,6 +5,12 @@ dtype promotion, and backend selection: on CPU the kernels execute in
 ``interpret=True`` mode (Python emulation of the kernel body — the
 correctness path used by CI); on TPU they compile to Mosaic; any other
 backend raises.
+
+Every ``pallas_call`` carries an explicit ``name=`` (``paged_attention``,
+``paged_prefill_attention``, ``ssd_scan``, ``flash_attention``,
+``grouped_matmul``). On the chip it names the custom call, and so the op a
+device trace shows (``%paged_attention.12 = ...``), whatever jitted function
+the kernel is traced from.
 """
 from __future__ import annotations
 

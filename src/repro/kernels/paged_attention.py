@@ -202,6 +202,7 @@ def paged_prefill_bkgd(q, k_pages, v_pages, tables, start, window, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, c, g, d), q.dtype),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(tables, start, window, q, k_pages, v_pages)
 
 
@@ -243,4 +244,5 @@ def paged_attention_bkgd(q, k_pages, v_pages, tables, pos, window, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(tables, pos, window, q, k_pages, v_pages)

@@ -99,4 +99,5 @@ def ssd_scan_bhsp(xdt, a_log, B, C, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((bh, s, p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(xdt, a_log, B, C)

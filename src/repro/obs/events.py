@@ -46,7 +46,10 @@ EVENT_SCHEMA: Dict[str, FrozenSet[str]] = {
     "preempt": frozenset({"req", "tenant", "slot", "cause", "n_preempted"}),
     "budget_skip": frozenset({"req", "tenant", "held", "need", "budget"}),
     "defer": frozenset({"req", "tenant", "cause"}),
-    # -- phase dispatches (spans: carry dur_s) ------------------------------
+    # -- phase dispatches (spans: carry dur_s, the interval of the engine's
+    # RunObs.span at the site: ``prefill`` and ``decode_horizon`` end in a
+    # host fetch; a paged ``prefill_round``'s dur_s is the round's host time,
+    # since its dispatch returns before the device ends) ------------------
     "prefill": frozenset({"req", "tenant", "slot", "prompt_len", "dur_s"}),
     "prefill_round": frozenset({"lanes", "width", "dur_s"}),
     "decode_horizon": frozenset({"k", "width", "active", "full", "dur_s"}),

@@ -12,10 +12,16 @@ cost of a counter bump is one dict-free attribute add.
 ``Histogram.percentile`` implements the same linear-interpolation rule as
 ``numpy.percentile``'s default, pinned by ``tests/test_obs.py`` against
 numpy itself.
+
+``RunObs.span`` is the engine's one span hook: a profiler annotation on
+the device trace's clock, a ``span_s[<name>]`` / ``span_n[<name>]`` counter
+pair in the run's registry, and (for the dispatch sites) the ``Tracer``
+span event, all three from one interval.
 """
 from __future__ import annotations
 
 import math
+import time
 from typing import Dict, List, Optional, Tuple
 
 
@@ -202,6 +208,43 @@ class MetricsRegistry:
         }
 
 
+class Span:
+    """One open ``RunObs.span``. ``set`` adds arguments known only inside
+    the span (they label the annotation and join the event payload);
+    ``dur_s`` holds the span's seconds once it has closed."""
+    __slots__ = ("_obs", "_name", "_event", "_args", "_ann", "_t0", "dur_s")
+
+    def __init__(self, obs: "RunObs", name: str, event: Optional[str],
+                 args: dict):
+        self._obs = obs
+        self._name = name
+        self._event = event
+        self._args = args
+        # imported here so that the rest of repro.obs (and
+        # launch/trace_report.py) runs without JAX
+        from jax.profiler import TraceAnnotation
+        self._ann = TraceAnnotation(name, **args)
+        self.dur_s = 0.0
+
+    def set(self, **args) -> None:
+        self._args.update(args)
+        self._ann.set_metadata(**args)
+
+    def __enter__(self) -> "Span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.dur_s = dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        obs = self._obs
+        obs.metrics.inc(f"span_s[{self._name}]", dt)
+        obs.metrics.inc(f"span_n[{self._name}]")
+        if self._event is not None and obs.tracer:
+            obs.tracer.emit(self._event, dur_s=dt, **self._args)
+
+
 class RunObs:
     """Per-run observability context: the metrics registry every run keeps
     (ServeStats is built from it) plus the — possibly null — event tracer.
@@ -225,3 +268,26 @@ class RunObs:
 
     def value(self, name: str, default: float = 0.0) -> float:
         return self.metrics.value(name, default)
+
+    def span(self, name: str, event: Optional[str] = None, **args) -> Span:
+        """Context manager timing one piece of engine work.
+
+        It opens ``jax.profiler.TraceAnnotation(name, **args)``, so a
+        ``jax.profiler`` trace shows the span on the host thread next to the
+        device's operations, and adds its ``perf_counter`` seconds and a
+        count to the registry as ``span_s[<name>]`` and ``span_n[<name>]``.
+        With ``event`` (a ``Tracer`` span type) and a live tracer it emits
+        that event on exit, ``args`` as its payload and ``dur_s`` from the
+        same interval. Off (no profiler session, no tracer) it costs one
+        annotation whose enter and exit do nothing, and two counter adds."""
+        return Span(self, name, event, args)
+
+    def spans(self) -> Dict[str, dict]:
+        """Every closed span's totals: name -> {"s": seconds, "n": count}."""
+        out = {}
+        for key, c in self.metrics.counters.items():
+            if key.startswith("span_s["):
+                name = key[7:-1]
+                out[name] = {"s": c.value,
+                             "n": int(self.metrics.value(f"span_n[{name}]"))}
+        return out

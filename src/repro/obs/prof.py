@@ -4,10 +4,10 @@ The measurement half of Synergy's optimistic-profiling loop, applied to the
 serve engine: the tenant profiler (serve/tenant.py) FITS sensitivity curves
 from two probes, the allocator plans from the fits — but until now nothing
 MEASURED what a dispatch actually costs, so the fits rode on analytic
-guesses. ``DispatchProfiler`` wraps every jitted hot path (batched prefill
-rounds, K-step decode horizons — the compaction gathers/scatters ride
-inside the horizon program and are tagged by its ``full`` flag) and records
-per-dispatch wall time with:
+guesses. ``DispatchProfiler`` wraps the jitted hot paths whose wall time ends
+in a host fetch — contiguous per-request prefills and K-step decode horizons
+(the compaction gathers/scatters ride inside the horizon program and are
+tagged by its ``full`` flag) — and records per-dispatch wall time with:
 
   * **compile-vs-execute attribution** — jit compiles one program per
     static signature (phase, width bucket, horizon K, full/compacted,
@@ -25,6 +25,10 @@ per-dispatch wall time with:
   * **per-tenant cost shares** — dispatch seconds split by lane/slot
     occupancy (a decode horizon whose bucket holds 3 rows of tenant A and
     1 of tenant B charges A 75% of the dispatch).
+
+A paged prefill round is not recorded: its dispatch returns before the
+device finishes, so its wall time would be the enqueue alone (its
+``serve.prefill_round`` span, ``RunObs.span``, times the round's host work).
 
 Records flow three ways: gauges + boundary-sampled series in the run's
 ``MetricsRegistry`` (``util[decode]`` etc. — the Chrome exporter renders

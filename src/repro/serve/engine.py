@@ -203,6 +203,9 @@ class ServeStats:
                                       # physical pool growth (grow_physical)
     replans: int = 0                  # allocator re-plans at reshape
                                       # boundaries (measured-rate refresh)
+    #: RunObs.span totals, name -> {"s": seconds, "n": count}, of every
+    #: span closed before the stats were built (all but ``serve.run``)
+    spans: dict = field(default_factory=dict)
 
 
 @dataclass
@@ -335,9 +338,18 @@ class ServeEngine:
     horizon boundaries feed ``ServeStats`` and its queue-depth/occupancy
     summaries.
 
+    Spans (``RunObs.span``) time each layer of the loop whatever the
+    tracer: ``serve.run``, ``serve.step``, ``serve.admit``,
+    ``serve.prefill``, ``serve.prefill_round``, ``serve.upload``,
+    ``serve.grow``, ``serve.horizon``, ``serve.fetch`` and
+    ``serve.unpack``. Each is a ``jax.profiler`` annotation (so a device
+    trace shows it beside the device's operations) and a count and total
+    in ``ServeStats.spans``; ``prefill_s`` and ``decode_s`` are the
+    ``serve.prefill`` and ``serve.horizon`` totals.
+
     ``profiler`` (an ``obs.DispatchProfiler``) turns on dispatch-level
-    profiling: every jitted hot path — per-request contiguous prefill,
-    lane-batched paged prefill rounds, K-step decode horizons (the
+    profiling: every jitted hot path that ends in a host fetch —
+    per-request contiguous prefill, K-step decode horizons (the
     compaction gather/scatter runs inside the horizon program, tagged by
     its ``full`` flag) — records wall time with compile-vs-execute
     attribution, an analytic roofline utilization ratio, and per-tenant
@@ -460,11 +472,13 @@ class ServeEngine:
 
     # -- prefill ---------------------------------------------------------------
     def _prefill_fn(self):
-        """(params, tokens[B, S]) -> (last logits [B, 1, V], cache pytree)."""
+        """(params, tokens[B, S]) -> (last logits [B, 1, V], cache pytree).
+        Each variant is named ``serve_prefill``, so its XLA module (and the
+        device trace's module line) reads ``jit_serve_prefill``."""
         cfg, model, max_len = self.cfg, self.model, self.max_len
 
         if cfg.family in _ATTN_PREFILL_FAMILIES:
-            def prefill(params, tokens):
+            def serve_prefill(params, tokens):
                 """One-pass attention prefill via the ``return_cache`` hook."""
                 logits, (k, v) = model.module.forward(cfg, params, tokens,
                                                       return_cache=True)
@@ -472,16 +486,16 @@ class ServeEngine:
                 widths = ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))
                 return logits[:, -1:], {"k": jnp.pad(k, widths),
                                         "v": jnp.pad(v, widths)}
-            return prefill
+            return serve_prefill
 
         if cfg.family == "ssm":
-            def prefill(params, tokens):
+            def serve_prefill(params, tokens):
                 """Chunked-SSD prefill: one pass over the prompt that
                 seeds the recurrent state (mamba2.prefill)."""
                 return model.module.prefill(cfg, params, tokens)
-            return prefill
+            return serve_prefill
 
-        def prefill(params, tokens):
+        def serve_prefill(params, tokens):
             """Recurrent prefill: scan decode steps (O(1) state per step)."""
             b, s = tokens.shape
             cache = model.init_cache(b, max_len)
@@ -496,22 +510,23 @@ class ServeEngine:
             (cache, logits), _ = jax.lax.scan(body, (cache, logits0),
                                               jnp.arange(s))
             return logits, cache
-        return prefill
+        return serve_prefill
 
     def _paged_prefill_fn(self):
         """Jitted lane-batched chunk prefill; ``cap`` is static (it sizes
         the MoE dispatch buffers — per-lane effective capacity is the traced
-        ``cap_rows``, so one program covers every prompt length)."""
+        ``cap_rows``, so one program covers every prompt length). Its XLA
+        module is ``jit_serve_prefill_round``."""
         mod, cfg = self.model.module, self.cfg
 
         @functools.partial(jax.jit, static_argnames=("cap",))
-        def chunk_fn(params, buffers, tokens, starts, n_valid, tables, state,
-                     cap_rows, cap):
+        def serve_prefill_round(params, buffers, tokens, starts, n_valid,
+                                tables, state, cap_rows, cap):
             return mod.paged_prefill_chunk(cfg, params, buffers, tokens,
                                            starts, tables, state, cap,
                                            n_valid=n_valid,
                                            cap_rows=cap_rows)
-        return chunk_fn
+        return serve_prefill_round
 
     # -- token selection (greedy / per-slot RNG lanes) -------------------------
     def _pick_fn(self):
@@ -568,7 +583,8 @@ class ServeEngine:
         eos = self.eos_token
         plan = self.sharding
 
-        def horizon(params, buffers, tok, pos, stop, idx, step0, h, full):
+        def serve_decode_horizon(params, buffers, tok, pos, stop, idx, step0,
+                                 h, full):
             if full:
                 # identity bucket: every slot decodes (idle rows are frozen
                 # and inert), so skip the gather/scatter copies of the pool
@@ -609,7 +625,7 @@ class ServeEngine:
             stop = stop.at[idx].set(s)
             return buffers, tok, pos, stop, blk
 
-        return self._jit_horizon(horizon)
+        return self._jit_horizon(serve_decode_horizon)
 
     def _paged_horizon_fn(self):
         """Jitted multi-step decode horizon over the block pool: gather the
@@ -622,8 +638,8 @@ class ServeEngine:
         eos = self.eos_token
         plan = self.sharding
 
-        def horizon(params, buffers, tok, pos, stop, tables, idx, step0, h,
-                    full):
+        def serve_decode_horizon(params, buffers, tok, pos, stop, tables, idx,
+                                 step0, h, full):
             if full:
                 t, p, s, tb = tok, pos, stop, tables
             else:
@@ -649,13 +665,15 @@ class ServeEngine:
             stop = stop.at[idx].set(s)
             return buffers, tok, pos, stop, blk
 
-        return self._jit_horizon(horizon)
+        return self._jit_horizon(serve_decode_horizon)
 
     def _jit_horizon(self, horizon):
         """jit with ``h`` (scan length) and ``full`` (identity bucket —
         no gather/scatter) static; sharded plans pin the cache to its
         NamedSharding and the state arrays to replicated so input
-        shardings stay stable across calls."""
+        shardings stay stable across calls. Both backends name the
+        function ``serve_decode_horizon``: one XLA module name,
+        ``jit_serve_decode_horizon``, for every horizon program."""
         plan = self.sharding
         if plan is not None:
             rep = plan.replicated()
@@ -667,8 +685,15 @@ class ServeEngine:
     # -- the engine loop ---------------------------------------------------------
     def run(self, requests: List[ServeRequest]
             ) -> Tuple[List[ServeRequest], ServeStats]:
-        """Serve ``requests`` to completion; returns (requests, stats)."""
+        """Serve ``requests`` to completion; returns (requests, stats).
+        The whole call, stats included, is the ``serve.run`` span."""
         reqs = list(requests)
+        c = RunObs(self.tracer)
+        with c.span("serve.run", requests=len(reqs)):
+            stats = self._run(reqs, c)
+        return reqs, stats
+
+    def _run(self, reqs: List[ServeRequest], c: RunObs) -> ServeStats:
         n_slots = self.n_slots if self.n_slots else max(len(reqs), 1)
         if self.injector is not None:
             # re-arm per run: warm-up double-runs and determinism checks
@@ -687,7 +712,6 @@ class ServeEngine:
         self._dmult_full = (self.sharding.axis_size("data")
                             if self.sharding is not None else 1)
         self._dmult = self._dmult_full
-        c = RunObs(self.tracer)
         tr = c.tracer
         if tr:
             tr.step = 0.0
@@ -703,7 +727,7 @@ class ServeEngine:
         wall = time.perf_counter() - t0
         if tr:
             tr.emit("run_end", steps=c.value("steps"), wall_s=wall)
-        return reqs, self._stats(reqs, c, n_slots, wall)
+        return self._stats(reqs, c, n_slots, wall)
 
     # -- stats aggregation -----------------------------------------------------
     def _finished(self, r: ServeRequest) -> bool:
@@ -798,8 +822,8 @@ class ServeEngine:
                                if rows_possible else 0.0),
             preemptions=int(m.value("preemptions")),
             block_report=c.block_report,
-            prefill_s=m.value("prefill_s"),
-            decode_s=m.value("decode_s"),
+            prefill_s=m.value("span_s[serve.prefill]"),
+            decode_s=m.value("span_s[serve.horizon]"),
             prefill_dispatches=int(m.value("prefill_dispatches")),
             decode_dispatches=int(m.value("decode_dispatches")),
             decode_horizon=self.decode_horizon,
@@ -823,6 +847,7 @@ class ServeEngine:
             scale_downs=int(m.value("scale_downs")),
             migrated_blocks=int(m.value("migrated_blocks")),
             replans=int(m.value("replans")),
+            spans=c.spans(),
         )
         return stats
 
@@ -873,26 +898,33 @@ class ServeEngine:
             return math.inf
         return self.tenants.slack(req, step)
 
-    def _evict(self, sched, state: _DecodeState, c: Optional[RunObs] = None):
+    def _evict(self, sched, state: _DecodeState, c: RunObs):
         """Evict finished requests and freeze their device rows, so a
         vacated slot gathered as horizon padding can never decode as live
         (or, paged, write KV through a stale block table)."""
         done_slots = [s for s, r in sched.active.items() if r.done]
         out = sched.evict_finished()
-        state.freeze(done_slots)
-        if c is not None and out:
+        self._upload(c, state.freeze, done_slots)
+        if c.tracer:
             for slot, r in zip(done_slots, out):
-                c.metrics.observe("latency_steps", r.latency_steps)
-                if c.tracer:
-                    t = (self.tenants.get(r.tenant)
-                         if self.tenants is not None else None)
-                    c.tracer.emit(
-                        "evict", req=r.job_id, tenant=r.tenant, slot=slot,
-                        latency_steps=r.latency_steps,
-                        finished_early=r.finished_early,
-                        slo_steps=t.slo_steps if t is not None else None,
-                        met=self._meets_slo(r))
+                t = (self.tenants.get(r.tenant)
+                     if self.tenants is not None else None)
+                c.tracer.emit(
+                    "evict", req=r.job_id, tenant=r.tenant, slot=slot,
+                    latency_steps=r.latency_steps,
+                    finished_early=r.finished_early,
+                    slo_steps=t.slo_steps if t is not None else None,
+                    met=self._meets_slo(r))
         return out
+
+    @staticmethod
+    def _upload(c: RunObs, fn, slots, *rows) -> None:
+        """One ``_DecodeState`` delta scatter (``set_rows``, ``set_tables``
+        or ``freeze``) over ``slots``, as a ``serve.upload`` span; none
+        when there are no rows to scatter."""
+        if len(slots):
+            with c.span("serve.upload", rows=len(slots)):
+                fn(slots, *rows)
 
     # -- fault injection + recovery (serve/chaos.py) ---------------------------
     def _fault_hold(self, sched):
@@ -1298,7 +1330,9 @@ class ServeEngine:
         """One horizon dispatch at a scheduler boundary (both backends):
         bucket the live rows, run the jitted horizon, unpack the [W, h]
         token block, update the counters and the scheduler clock. Returns
-        the per-row emitted counts in sorted-active order."""
+        the per-row emitted counts in sorted-active order. The dispatch and
+        its fetch are the ``serve.horizon`` span (``decode_s``), the fetch
+        alone ``serve.fetch``, the bookkeeping after it ``serve.unpack``."""
         act = sorted(sched.active)
         h = _pow2_floor(min(h, max(sched.active[s].max_new_tokens
                                    - len(sched.active[s].output)
@@ -1315,37 +1349,35 @@ class ServeEngine:
         args = (self.params, pool.buffers, state.tok, state.pos, state.stop)
         if state.tables is not None:
             args += (state.tables,)
-        t0 = time.perf_counter()
-        pool.buffers, state.tok, state.pos, state.stop, blk = self._horizon(
-            *args, jnp.asarray(idx), jnp.int32(sched.step), h=h, full=full)
-        c.inc("decode_dispatches")
-        blk = np.asarray(blk)                # the ONE [W, h] int32 fetch
-        c.inc("host_syncs")
-        dt = time.perf_counter() - t0
-        c.inc("decode_s", dt)
+        with c.span("serve.horizon", "decode_horizon", step=sched.step, k=h,
+                    width=len(idx), active=len(act), full=full) as span:
+            pool.buffers, state.tok, state.pos, state.stop, blk = \
+                self._horizon(*args, jnp.asarray(idx), jnp.int32(sched.step),
+                              h=h, full=full)
+            c.inc("decode_dispatches")
+            with c.span("serve.fetch"):
+                blk = np.asarray(blk)        # the ONE [W, h] int32 fetch
+            c.inc("host_syncs")
         prof = self.profiler
         if prof:
             # KV positions at dispatch start (outputs not yet extended);
             # tenants maps tenant -> live rows for the cost-share split.
             kv = sum(len(sched.active[s].prompt) + len(sched.active[s].output)
                      for s in act)
-            prof.record("decode", dt, width=len(idx), k=h, full=full,
-                        kv_pos_sum=kv,
+            prof.record("decode", span.dur_s, width=len(idx), k=h,
+                        full=full, kv_pos_sum=kv,
                         tenants=Counter(sched.active[s].tenant for s in act),
                         obs=c)
-        counts = self._unpack_horizon(sched, act, rows, blk, h, n_slots, c)
-        c.inc("rows_decoded", len(idx) * h)
-        c.hi("max_active", len(act))
-        c.inc("steps", h)
-        c.metrics.observe("horizon_k", h)
-        if c.tracer:
-            c.tracer.emit("decode_horizon", step=sched.step, k=h,
-                          width=len(idx), active=len(act), full=full,
-                          dur_s=dt)
-        sched.step += h
-        if c.tracer:
-            c.tracer.step = sched.step
-        self._sample_boundary(sched, pool, c, n_slots)
+        with c.span("serve.unpack"):
+            counts = self._unpack_horizon(sched, act, rows, blk, h, n_slots,
+                                          c)
+            c.inc("rows_decoded", len(idx) * h)
+            c.hi("max_active", len(act))
+            c.inc("steps", h)
+            sched.step += h
+            if c.tracer:
+                c.tracer.step = sched.step
+            self._sample_boundary(sched, pool, c, n_slots)
         return counts
 
     def _unpack_horizon(self, sched, act, rows, blk, h, n_slots,
@@ -1382,10 +1414,56 @@ class ServeEngine:
         self._submit_all(sched, pool, reqs)
 
         state = _DecodeState(n_slots, sharding=self.sharding)
-        tr = c.tracer
-        prof = self.profiler
-
         while sched.has_work:
+            with c.span("serve.step"):
+                admitted = self._admit(sched, pool, state, c, n_slots, reqs)
+                if admitted:
+                    with c.span("serve.prefill", requests=len(admitted)):
+                        for r in admitted:
+                            self._contiguous_prefill(pool, r, sched.step, c)
+                    self._upload(
+                        c, state.set_rows, [r.slot for r in admitted],
+                        [r.output[-1] for r in admitted],
+                        [len(r.prompt) for r in admitted],
+                        [len(r.prompt) + r.max_new_tokens - 1
+                         for r in admitted])
+                self._evict(sched, state, c)  # satisfied by prefill / EOS
+                if not sched.active:
+                    nxt = sched.next_arrival()
+                    if nxt is None:
+                        break
+                    if self.injector is not None and nxt <= sched.step:
+                        # everything waiting is held (a slowdown/storm
+                        # window): jump to the next event that could
+                        # unstall admission.
+                        unb = self._next_unblock(sched)
+                        nxt = unb if unb is not None else sched.step + 1
+                    sched.step = max(sched.step + 1, int(math.ceil(nxt)))
+                    if c.tracer:
+                        c.tracer.step = sched.step
+                    continue
+
+                # pool.write's eager scatter loses the NamedSharding
+                # layout; restore it only on rounds that actually admitted
+                # (the horizon's out_shardings keeps the cache sharded
+                # otherwise).
+                if self.sharding is not None and admitted:
+                    pool.buffers = self.sharding.reshard_cache(pool.buffers)
+
+                with c.span("serve.grow") as span:
+                    h = self._pick_h(sched, sorted(sched.active))
+                    span.set(h=h)
+                self._decode_boundary(sched, pool, state, c, n_slots,
+                                      self._dmult, h)
+        self._evict(sched, state, c)
+
+    def _admit(self, sched, pool, state, c: RunObs, n_slots: int,
+               reqs: List[ServeRequest]) -> List[ServeRequest]:
+        """The admission half of a boundary (both backends), as the
+        ``serve.admit`` span: due faults and reshapes, eviction, the
+        scheduler's admission, chaos retries. Returns the requests
+        admitted, in prefill order."""
+        with c.span("serve.admit") as span:
             if self.injector is not None:
                 self._apply_faults(sched, pool, state, c, n_slots, reqs)
             self._elastic_poll(sched, pool, state, c)
@@ -1394,66 +1472,42 @@ class ServeEngine:
             if self.injector is not None or self.elastic is not None:
                 self._chaos_admission(sched, pool, c)
             admitted = sched.drain_prefill()
-            t0 = time.perf_counter()
-            for r in admitted:
-                rt0 = time.perf_counter() if (tr or prof) else 0.0
-                tokens = jnp.asarray(
-                    np.asarray(r.prompt, np.int32))[None, :]
-                logits, row = self._prefill(self.params, tokens)
-                c.inc("prefill_dispatches")
-                pool.write(r.slot, row)
-                tok = int(self._select_tokens(logits[:, -1], [r.slot],
-                                              ~sched.step, c)[0])
-                r.output.append(tok)
-                if self.record_logits:
-                    r.prefill_logits = np.asarray(
-                        logits[0, -1].astype(jnp.float32))
-                if self.eos_token is not None and tok == self.eos_token:
-                    r.finished_early = True
-                if tr or prof:
-                    rdt = time.perf_counter() - rt0
-                    if tr:
-                        tr.emit("prefill", req=r.job_id, tenant=r.tenant,
-                                slot=r.slot, prompt_len=len(r.prompt),
-                                dur_s=rdt)
-                    if prof:
-                        # contiguous prefill jits one program per prompt
-                        # length — seq is the static half of the signature.
-                        prof.record("prefill", rdt, seq=len(r.prompt),
-                                    tokens=len(r.prompt),
-                                    tenants={r.tenant: 1}, obs=c)
-            if admitted:
-                c.inc("prefill_s", time.perf_counter() - t0)
-                state.set_rows(
-                    [r.slot for r in admitted],
-                    [r.output[-1] for r in admitted],
-                    [len(r.prompt) for r in admitted],
-                    [len(r.prompt) + r.max_new_tokens - 1 for r in admitted])
-            self._evict(sched, state, c)  # satisfied by prefill alone / EOS
-            if not sched.active:
-                nxt = sched.next_arrival()
-                if nxt is None:
-                    break
-                if self.injector is not None and nxt <= sched.step:
-                    # everything waiting is held (a slowdown/storm window):
-                    # jump to the next event that could unstall admission.
-                    unb = self._next_unblock(sched)
-                    nxt = unb if unb is not None else sched.step + 1
-                sched.step = max(sched.step + 1, int(math.ceil(nxt)))
-                if tr:
-                    tr.step = sched.step
-                continue
+            span.set(admitted=len(admitted))
+        return admitted
 
-            # pool.write's eager scatter loses the NamedSharding layout;
-            # restore it only on rounds that actually admitted (the
-            # horizon's out_shardings keeps the cache sharded otherwise).
-            if self.sharding is not None and admitted:
-                pool.buffers = self.sharding.reshard_cache(pool.buffers)
+    def _contiguous_prefill(self, pool: CachePool, r: ServeRequest, step,
+                            c: RunObs) -> None:
+        """One request's exact-length prefill up to its first token's
+        fetch, as a ``serve.prefill_round`` span (the Tracer's ``prefill``
+        event, and the profiler's ``prefill`` record, share its interval)."""
+        with c.span("serve.prefill_round", "prefill", req=r.job_id,
+                    tenant=r.tenant, slot=r.slot,
+                    prompt_len=len(r.prompt)) as span:
+            tokens = jnp.asarray(np.asarray(r.prompt, np.int32))[None, :]
+            logits, row = self._prefill(self.params, tokens)
+            c.inc("prefill_dispatches")
+            pool.write(r.slot, row)
+            tok = int(self._select_tokens(logits[:, -1], [r.slot], ~step,
+                                          c)[0])
+            self._first_token(r, tok)
+            if self.record_logits:
+                r.prefill_logits = np.asarray(
+                    logits[0, -1].astype(jnp.float32))
+        if self.profiler:
+            # contiguous prefill jits one program per prompt length — seq
+            # is the static half of the signature.
+            self.profiler.record("prefill", span.dur_s, seq=len(r.prompt),
+                                 tokens=len(r.prompt),
+                                 tenants={r.tenant: 1}, obs=c)
 
-            h = self._pick_h(sched, sorted(sched.active))
-            self._decode_boundary(sched, pool, state, c, n_slots,
-                                  self._dmult, h)
-        self._evict(sched, state, c)
+    def _first_token(self, r: ServeRequest, tok: int) -> None:
+        """Append the token a prefill picked; the first one a request ever
+        receives stamps ``t_first_token`` (a preempted request keeps it)."""
+        r.output.append(tok)
+        if r.t_first_token is None:
+            r.t_first_token = time.perf_counter()
+        if self.eos_token is not None and tok == self.eos_token:
+            r.finished_early = True
 
     # -- paged loop --------------------------------------------------------------
     def _next_lane_req(self, queue: deque, lanes) -> ServeRequest:
@@ -1488,18 +1542,15 @@ class ServeEngine:
         prefix cache, and on its final chunk samples the request's first
         token from its last-valid-position logits; the freed lane is then
         refilled from the queue so long prompts never serialize behind
-        short ones."""
-        if not reqs:
-            return
-        bs, mb = pool.block_size, pool.max_blocks
+        short ones. Each round is a ``serve.prefill_round`` span; its
+        Tracer ``prefill_round`` event's ``dur_s`` is the round's host time
+        (packing, dispatch, block commits, the token pick's fetch), and the
+        dispatch alone is not timed: it returns before the device ends."""
         is_moe = self.cfg.family == "moe"
-        cap_static = self.max_len if is_moe else 0
         if is_moe:
             from repro.models.moe import capacity as moe_capacity
         queue = deque(reqs)
         lanes: List[_PrefillLane] = []
-        tr = c.tracer
-        prof = self.profiler
         while queue or lanes:
             while queue and len(lanes) < self.prefill_lanes:
                 r = self._next_lane_req(queue, lanes)
@@ -1513,66 +1564,66 @@ class ServeEngine:
                              if is_moe else 0),
                     state=state))
             w = _bucket(len(lanes), self.prefill_lanes)
-            tokens = np.zeros((w, bs), np.int32)
-            starts = np.zeros((w,), np.int32)
-            nv = np.zeros((w,), np.int32)
-            caps = np.zeros((w,), np.int32)
-            tables = np.full((w, mb), -1, np.int32)
-            for i, ln in enumerate(lanes):
-                n = min(bs, len(ln.prompt) - ln.ptr)
-                tokens[i, :n] = ln.prompt[ln.ptr:ln.ptr + n]
-                starts[i], nv[i], caps[i] = ln.ptr, n, ln.cap_row
-                tables[i] = pool.tables[ln.req.slot]
-            state = None
-            if is_moe:
-                cols = [ln.state for ln in lanes]
-                cols += [np.zeros_like(cols[0])] * (w - len(lanes))
-                state = jnp.asarray(np.concatenate(cols, axis=1))
-            rt0 = time.perf_counter() if (tr or prof) else 0.0
-            logits, pool.buffers, new_state = self._prefill(
-                self.params, pool.buffers, jnp.asarray(tokens),
-                jnp.asarray(starts), jnp.asarray(nv), jnp.asarray(tables),
-                state, jnp.asarray(caps), cap=cap_static)
-            c.inc("prefill_dispatches")
-            if tr or prof:
-                rdt = time.perf_counter() - rt0
-                if tr:
-                    tr.emit("prefill_round", lanes=len(lanes), width=w,
-                            dur_s=rdt)
-                if prof:
-                    # one program per width bucket; padded lanes compute,
-                    # so the roofline counts the full [w, bs] dispatch.
-                    prof.record("prefill_round", rdt, width=w, tokens=w * bs,
-                                kv_pos_sum=int(starts.sum()),
-                                tenants=Counter(ln.req.tenant
-                                                for ln in lanes), obs=c)
+            with c.span("serve.prefill_round", "prefill_round",
+                        lanes=len(lanes), width=w):
+                lanes = self._paged_prefill_round(pool, lanes, w, step, c)
+
+    def _paged_prefill_round(self, pool: BlockManager,
+                             lanes: List[_PrefillLane], w: int, step: int,
+                             c: RunObs) -> List[_PrefillLane]:
+        """One chunk round of ``_batched_paged_prefill`` at lane width
+        ``w``: pack and dispatch one chunk of every lane, commit full
+        blocks, and pick (one fetch) the first token of every lane whose
+        prompt ended. Returns the lanes still prefilling."""
+        bs, mb = pool.block_size, pool.max_blocks
+        is_moe = self.cfg.family == "moe"
+        tokens = np.zeros((w, bs), np.int32)
+        starts = np.zeros((w,), np.int32)
+        nv = np.zeros((w,), np.int32)
+        caps = np.zeros((w,), np.int32)
+        tables = np.full((w, mb), -1, np.int32)
+        for i, ln in enumerate(lanes):
+            n = min(bs, len(ln.prompt) - ln.ptr)
+            tokens[i, :n] = ln.prompt[ln.ptr:ln.ptr + n]
+            starts[i], nv[i], caps[i] = ln.ptr, n, ln.cap_row
+            tables[i] = pool.tables[ln.req.slot]
+        state = None
+        if is_moe:
+            cols = [ln.state for ln in lanes]
+            cols += [np.zeros_like(cols[0])] * (w - len(lanes))
+            state = jnp.asarray(np.concatenate(cols, axis=1))
+        logits, pool.buffers, new_state = self._prefill(
+            self.params, pool.buffers, jnp.asarray(tokens),
+            jnp.asarray(starts), jnp.asarray(nv), jnp.asarray(tables),
+            state, jnp.asarray(caps), cap=self.max_len if is_moe else 0)
+        c.inc("prefill_dispatches")
+        if new_state is not None:
+            new_state = np.asarray(new_state)
+        done_idx: List[int] = []
+        live: List[_PrefillLane] = []
+        for i, ln in enumerate(lanes):
+            n = int(nv[i])
             if new_state is not None:
-                new_state = np.asarray(new_state)
-            done_idx: List[int] = []
-            live: List[_PrefillLane] = []
-            for i, ln in enumerate(lanes):
-                n = int(nv[i])
-                if new_state is not None:
-                    ln.state = new_state[:, i:i + 1]
-                if n == bs:        # a full block is final: cacheable
-                    pool.commit_block(
-                        ln.req.slot, ln.ptr // bs,
-                        None if ln.state is None else ln.state.copy())
-                ln.ptr += n
-                if ln.ptr >= len(ln.prompt):
-                    done_idx.append(i)
-                else:
-                    live.append(ln)
-            if done_idx:
-                slots = [lanes[i].req.slot for i in done_idx]
-                toks = self._select_tokens(
-                    logits[np.asarray(done_idx), -1], slots, ~step, c)
-                for t, i in zip(toks, done_idx):
-                    lanes[i].req.output.append(int(t))
-                    if self.record_logits:
-                        lanes[i].req.prefill_logits = np.asarray(
-                            logits[i, -1].astype(jnp.float32))
-            lanes = live
+                ln.state = new_state[:, i:i + 1]
+            if n == bs:        # a full block is final: cacheable
+                pool.commit_block(
+                    ln.req.slot, ln.ptr // bs,
+                    None if ln.state is None else ln.state.copy())
+            ln.ptr += n
+            if ln.ptr >= len(ln.prompt):
+                done_idx.append(i)
+            else:
+                live.append(ln)
+        if done_idx:
+            slots = [lanes[i].req.slot for i in done_idx]
+            toks = self._select_tokens(
+                logits[np.asarray(done_idx), -1], slots, ~step, c)
+            for t, i in zip(toks, done_idx):
+                self._first_token(lanes[i].req, int(t))
+                if self.record_logits:
+                    lanes[i].req.prefill_logits = np.asarray(
+                        logits[i, -1].astype(jnp.float32))
+        return live
 
     def _growth_blocks_needed(self, sched, pool: BlockManager, pos_np,
                               stop_np, h: int) -> int:
@@ -1660,84 +1711,79 @@ class ServeEngine:
                              sharding=self.sharding)
         pos_np = np.zeros((n_slots,), np.int64)
         stop_np = np.zeros((n_slots,), np.int64)
-        tr = c.tracer
         peak_report = pool.report()
 
         while sched.has_work:
-            if self.injector is not None:
-                self._apply_faults(sched, pool, state, c, n_slots, reqs)
-            self._elastic_poll(sched, pool, state, c)
-            self._evict(sched, state, c)
-            sched.admit(hold=self._fault_hold(sched))
-            if self.injector is not None or self.elastic is not None:
-                self._chaos_admission(sched, pool, c)
-            admitted = sched.drain_prefill()
-            if admitted:
-                t0 = time.perf_counter()
-                self._batched_paged_prefill(pool, admitted, sched.step, c)
-                c.inc("prefill_s", time.perf_counter() - t0)
-                for r in admitted:
-                    pos_np[r.slot] = len(r.prompt)
-                    stop_np[r.slot] = len(r.prompt) + r.max_new_tokens - 1
-                    if (self.eos_token is not None
-                            and r.output[-1] == self.eos_token):
-                        r.finished_early = True
-                slots = [r.slot for r in admitted]
-                state.set_rows(slots,
-                               [r.output[-1] for r in admitted],
-                               [int(pos_np[s]) for s in slots],
-                               [int(stop_np[s]) for s in slots])
-                snap = pool.report()     # pool pressure peaks can be
-                                         # prefill-only (max_new == 1 runs)
+            with c.span("serve.step"):
+                admitted = self._admit(sched, pool, state, c, n_slots, reqs)
+                if admitted:
+                    with c.span("serve.prefill", requests=len(admitted)):
+                        self._batched_paged_prefill(pool, admitted,
+                                                    sched.step, c)
+                    slots = [r.slot for r in admitted]
+                    for r in admitted:
+                        pos_np[r.slot] = len(r.prompt)
+                        stop_np[r.slot] = len(r.prompt) + r.max_new_tokens - 1
+                    self._upload(c, state.set_rows, slots,
+                                 [r.output[-1] for r in admitted],
+                                 [int(pos_np[s]) for s in slots],
+                                 [int(stop_np[s]) for s in slots])
+                    snap = pool.report()     # pool pressure peaks can be
+                                             # prefill-only (max_new == 1)
+                    if snap["used_blocks"] >= peak_report["used_blocks"]:
+                        peak_report = snap
+                self._evict(sched, state, c)  # satisfied by prefill / EOS
+                if not sched.active:
+                    nxt = sched.next_arrival()
+                    if nxt is None:
+                        break
+                    if not admitted and nxt <= sched.step:
+                        if self.injector is None and self.elastic is None:
+                            raise RuntimeError(
+                                "paged KV pool cannot admit any waiting "
+                                "request; grow n_blocks or lower the "
+                                "watermark")
+                        # graceful degradation: a shrink/hold made
+                        # everything momentarily inadmissible — advance to
+                        # the next event that could unstall (hold release,
+                        # backoff retry, pending fault, later arrival);
+                        # retries bound the stall, dropping what the pool
+                        # can never hold.
+                        unb = self._next_unblock(sched)
+                        nxt = unb if unb is not None else sched.step + 1
+                    sched.step = max(sched.step + 1, int(math.ceil(nxt)))
+                    if c.tracer:
+                        c.tracer.step = sched.step
+                    continue
+
+                if self.sharding is not None and admitted:
+                    pool.buffers = self.sharding.reshard_cache(pool.buffers)
+
+                with c.span("serve.grow") as span:
+                    h = self._pick_h(sched, sorted(sched.active))
+                    h, n_pre, victims = self._ensure_growth(
+                        sched, pool, pos_np, stop_np, h, c)
+                    c.inc("preemptions", n_pre)
+                    span.set(h=h)
+                self._upload(c, state.freeze, victims)
+                if not sched.active:    # chaos: sole request dropped on
+                    continue            # exhaustion — back to admission
+                # delta-sync the device table mirror: only rows dirtied by
+                # admission / growth (freed rows stay stale — they are
+                # frozen and write-masked, so the staleness is
+                # unobservable).
+                dirty = [s for s in pool.drain_dirty() if s in sched.active]
+                self._upload(c, state.set_tables, dirty,
+                             pool.tables[np.asarray(dirty, np.int64)])
+
+                act = sorted(sched.active)
+                counts = self._decode_boundary(sched, pool, state, c,
+                                               n_slots, self._dmult, h)
+                for slot, m in zip(act, counts):
+                    pos_np[slot] += m
+                snap = pool.report()
                 if snap["used_blocks"] >= peak_report["used_blocks"]:
-                    peak_report = snap
-            self._evict(sched, state, c)  # satisfied by prefill alone / EOS
-            if not sched.active:
-                nxt = sched.next_arrival()
-                if nxt is None:
-                    break
-                if not admitted and nxt <= sched.step:
-                    if self.injector is None and self.elastic is None:
-                        raise RuntimeError(
-                            "paged KV pool cannot admit any waiting request; "
-                            "grow n_blocks or lower the watermark")
-                    # graceful degradation: a shrink/hold made everything
-                    # momentarily inadmissible — advance to the next event
-                    # that could unstall (hold release, backoff retry,
-                    # pending fault, later arrival); retries bound the
-                    # stall, dropping what the pool can never hold.
-                    unb = self._next_unblock(sched)
-                    nxt = unb if unb is not None else sched.step + 1
-                sched.step = max(sched.step + 1, int(math.ceil(nxt)))
-                if tr:
-                    tr.step = sched.step
-                continue
-
-            if self.sharding is not None and admitted:
-                pool.buffers = self.sharding.reshard_cache(pool.buffers)
-
-            h = self._pick_h(sched, sorted(sched.active))
-            h, n_pre, victims = self._ensure_growth(sched, pool, pos_np,
-                                                    stop_np, h, c)
-            c.inc("preemptions", n_pre)
-            state.freeze(victims)
-            if not sched.active:    # chaos: sole request dropped on
-                continue            # exhaustion — back to admission
-            # delta-sync the device table mirror: only rows dirtied by
-            # admission / growth (freed rows stay stale — they are frozen
-            # and write-masked, so the staleness is unobservable).
-            dirty = [s for s in pool.drain_dirty() if s in sched.active]
-            if dirty:
-                state.set_tables(dirty, pool.tables[np.asarray(dirty)])
-
-            act = sorted(sched.active)
-            counts = self._decode_boundary(sched, pool, state, c, n_slots,
-                                           self._dmult, h)
-            for slot, m in zip(act, counts):
-                pos_np[slot] += m
-            snap = pool.report()
-            if snap["used_blocks"] >= peak_report["used_blocks"]:
-                peak_report = snap          # report the pool at peak pressure
+                    peak_report = snap      # the pool at peak pressure
         self._evict(sched, state, c)
         c.block_report = peak_report
         c.inc("prefix_hits", pool.prefix_blocks_hit)

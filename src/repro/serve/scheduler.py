@@ -65,10 +65,15 @@ class ServeRequest:
     #: separately from ``unfinished`` (see ServeStats)
     dropped: bool = False
     drop_cause: Optional[str] = None
-    # wall clocks: t_arrived is stamped when the engine clock first passes
-    # arrival_time (NOT at admission), so latency_s includes queue wait.
+    # wall clocks (perf_counter): t_arrived is stamped when the engine clock
+    # first passes arrival_time (NOT at admission), so latency_s includes
+    # queue wait. t_admitted is the FIRST admission and t_first_token the
+    # first token a prefill picked (a preempted request keeps both), so a
+    # request's time splits into queued [due, t_admitted), prefill
+    # [t_admitted, t_first_token) and decode [t_first_token, t_finished].
     t_arrived: Optional[float] = None
     t_admitted: Optional[float] = None
+    t_first_token: Optional[float] = None
     t_finished: Optional[float] = None
     #: the prompt's last-position logits [vocab] (f32), kept only by an
     #: engine built with ``record_logits=True`` (a correctness check's hook)
@@ -210,7 +215,8 @@ class ContinuousScheduler:
                 break
             req.slot = slot
             req.admitted_at = float(self.step)
-            req.t_admitted = time.perf_counter()
+            if req.t_admitted is None:
+                req.t_admitted = time.perf_counter()
             self.active[slot] = req
             self.waiting.remove(req)
             self.prefill_queue.append(req)
@@ -237,6 +243,7 @@ class ContinuousScheduler:
         Its slot and blocks are freed and its generated tokens discarded;
         after re-admission the deterministic prefill + greedy decode
         regenerate them identically, so preemption is invisible in outputs.
+        Its wall stamps of the first admission and first token stay.
         """
         if req.slot is None or self.active.get(req.slot) is not req:
             raise ValueError("can only preempt an active request")
@@ -249,7 +256,6 @@ class ContinuousScheduler:
         del self.active[req.slot]
         req.slot = None
         req.admitted_at = None
-        req.t_admitted = None
         req.output = []
         req.finished_early = False
         req.n_preempted += 1

@@ -5,7 +5,9 @@ that is described, not attached, and refuses what the chip's compiler
 would refuse (block shapes off the (8, 128) tiling, ops with no Mosaic
 lowering, too much VMEM). Interpret-mode tests cannot see any of that.
 Each test asserts the kernel reached the program as Mosaic
-(``tpu_custom_call``), not as interpreted jnp.
+(``tpu_custom_call``), not as interpreted jnp, under the name its
+``pallas_call`` gives it: the op name a device trace shows, which the chip
+benchmark's kernel rooflines look up.
 
 Widths: qwen2-0.5b attention (14 q heads over 2 kv heads, head_dim 64,
 block_size 16, bf16), mamba2-780m SSD (48 heads of headdim 64, state 128,
@@ -64,6 +66,13 @@ def _compile_text(fn, sharding, *shapes):
     return jax.jit(fn).lower(*structs).compile().as_text()
 
 
+def _custom_call_names(text):
+    """The HLO names of the Mosaic custom calls, without ``ROOT`` and the
+    numeric suffix."""
+    return {line.split(" = ", 1)[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
+            for line in text.splitlines() if "tpu_custom_call" in line}
+
+
 def test_paged_decode_compiles(one_chip):
     fn = functools.partial(pa.paged_attention_bkgd, interpret=False)
     text = _compile_text(fn, one_chip,
@@ -73,6 +82,7 @@ def test_paged_decode_compiles(one_chip):
                          ((SLOTS, MAX_BLOCKS), I32), ((SLOTS,), I32),
                          ((1,), I32))
     assert "tpu_custom_call" in text
+    assert _custom_call_names(text) == {"paged_attention"}
 
 
 def test_paged_prefill_compiles(one_chip):
@@ -83,6 +93,7 @@ def test_paged_prefill_compiles(one_chip):
                          ((N_BLOCKS, HKV, BS, D), BF16),
                          ((4, MAX_BLOCKS), I32), ((4,), I32), ((1,), I32))
     assert "tpu_custom_call" in text
+    assert _custom_call_names(text) == {"paged_prefill_attention"}
 
 
 def test_ssd_scan_compiles(one_chip):
@@ -95,6 +106,7 @@ def test_ssd_scan_compiles(one_chip):
                          ((bh, SSM_SEQ, SSM_N), F32),
                          ((bh, SSM_SEQ, SSM_N), F32))
     assert "tpu_custom_call" in text
+    assert _custom_call_names(text) == {"ssd_scan"}
 
 
 def test_flash_attention_compiles(one_chip):
@@ -106,6 +118,7 @@ def test_flash_attention_compiles(one_chip):
                          ((b * HKV, s, D), BF16),
                          ((b * HKV, s, D), BF16))
     assert "tpu_custom_call" in text
+    assert _custom_call_names(text) == {"flash_attention"}
 
 
 def test_grouped_matmul_compiles(one_chip):
@@ -116,3 +129,4 @@ def test_grouped_matmul_compiles(one_chip):
                          ((experts, d_model, d_ff), BF16),
                          ((experts,), I32))
     assert "tpu_custom_call" in text
+    assert _custom_call_names(text) == {"grouped_matmul"}
