@@ -34,6 +34,26 @@ def scan_layers(cfg, body, carry, xs):
     return carry, stacked
 
 
+def scan_paged_layers(cfg, body, x, pool, xs):
+    """``scan_layers`` with the paged K/V pool (``{"k", "v"}`` leaves
+    ``[L, NB, Hkv, BS, D]``) in the carry instead of the scanned inputs and
+    outputs: ``body(x, pool, layer, xs_l) -> (x, pool, ys_l)``, ``layer``
+    the int32 index of the layer that ``xs_l`` slices. Scanned as xs and
+    ys, every pass would slice each layer's slab out and stack a new pool
+    back up; carried, each layer writes its K/V into the one pool buffer
+    in place (``paged_kv_write``). Returns (x, pool, ys)."""
+    def step(carry, scanned):
+        x, pool = carry
+        layer, xs_l = scanned
+        x, pool, ys_l = body(x, pool, layer, xs_l)
+        return (x, pool), ys_l
+
+    layers = jnp.arange(jax.tree_util.tree_leaves(xs)[0].shape[0],
+                        dtype=jnp.int32)
+    (x, pool), ys = scan_layers(cfg, step, (x, pool), (layers, xs))
+    return x, pool, ys
+
+
 def _init_dense(key, shape, dtype, scale: Optional[float] = None):
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(fan_in)
@@ -264,18 +284,28 @@ DECODE_BACKENDS = ("contiguous", "paged")
 
 
 class PagedKV(NamedTuple):
-    """One layer's paged decode cache: block-pool K/V plus the block table.
+    """One layer's view of the paged decode cache: the whole block pool,
+    the layer it addresses, and the block table.
 
-    k, v: [n_blocks, Hkv, block_size, D] — the shared block pool, head-major
-    so a (block, kv head) tile is one contiguous [block_size, D] slab (the
-    block shape the Pallas paged kernels can DMA on a TPU).
+    k, v: [L, n_blocks, Hkv, block_size, D] — the shared block pool of
+    every layer, head-major so a (block, kv head) tile is one contiguous
+    [block_size, D] slab (the block shape the Pallas paged kernels can DMA
+    on a TPU). The pool travels whole so that a layer's write updates it in
+    place (``scan_paged_layers``).
     tables: [B, max_blocks] int32 — row b's logical position p lives in block
     ``tables[b, p // block_size]`` at offset ``p % block_size``; -1 marks an
     unassigned table column (padding rows read nothing and write nowhere).
+    layer: int32 scalar — the layer whose K/V this call writes and reads.
     """
     k: jax.Array
     v: jax.Array
     tables: jax.Array
+    layer: jax.Array
+
+    @property
+    def kv_len(self) -> int:
+        """Logical positions a row's table spans."""
+        return self.tables.shape[1] * self.k.shape[3]
 
 
 def plan_decode_backend(cfg, kv_cache) -> str:
@@ -299,14 +329,14 @@ def plan_decode_backend(cfg, kv_cache) -> str:
 
 
 def paged_kv_write(pkv: PagedKV, k, v, positions, valid=None) -> PagedKV:
-    """Write k/v [B, C, Hkv, D] at logical ``positions`` [B, C] through the
-    block table. Rows whose table has no block for a position (padding rows,
-    ``tables[b, p // bs] < 0``) are dropped, never scattered into a live
-    block; ``valid`` [B, C] additionally drops padded lane positions of a
-    batched prefill chunk (a short final chunk padded to block_size must not
-    scatter garbage into its own — or, prefix-shared, anyone else's —
-    blocks)."""
-    nb, _, bs, _ = pkv.k.shape
+    """Write k/v [B, C, Hkv, D] at logical ``positions`` [B, C] of layer
+    ``pkv.layer`` through the block table. Rows whose table has no block
+    for a position (padding rows, ``tables[b, p // bs] < 0``) are dropped,
+    never scattered into a live block; ``valid`` [B, C] additionally drops
+    padded lane positions of a batched prefill chunk (a short final chunk
+    padded to block_size must not scatter garbage into its own — or,
+    prefix-shared, anyone else's — blocks) and frozen decode rows."""
+    _, nb, hkv, bs, _ = pkv.k.shape
     mb = pkv.tables.shape[1]
     p = jnp.asarray(positions, jnp.int32)
     col = jnp.clip(p // bs, 0, mb - 1)           # pad positions may overrun
@@ -314,24 +344,34 @@ def paged_kv_write(pkv: PagedKV, k, v, positions, valid=None) -> PagedKV:
     blk = jnp.where((blk >= 0) & (p // bs < mb), blk, nb)  # oob -> dropped
     if valid is not None:
         blk = jnp.where(valid, blk, nb)
-    off = p % bs
-    # the two [B, C] index arrays straddle the head slice, so the indexed
-    # update is laid out [B, C, Hkv, D] — k/v's own layout
-    nk = pkv.k.at[blk, :, off].set(k.astype(pkv.k.dtype), mode="drop")
-    nv = pkv.v.at[blk, :, off].set(v.astype(pkv.v.dtype), mode="drop")
-    return PagedKV(nk, nv, pkv.tables)
+    # Index all four leading dims (layer, block, head, offset) so each
+    # update is one row of D: the scatter then keeps the pool's own layout
+    # and XLA writes the carried buffer in place. A [Hkv, D] window
+    # between the block and offset indices made the TPU compiler relayout
+    # the pool around the scatter. Updates are k/v's own [B, C, Hkv, D].
+    shape = blk.shape + (hkv,)
+    idx = (jnp.broadcast_to(pkv.layer, shape),
+           jnp.broadcast_to(blk[..., None], shape),
+           jnp.broadcast_to(jnp.arange(hkv, dtype=jnp.int32), shape),
+           jnp.broadcast_to((p % bs)[..., None], shape))
+    nk = pkv.k.at[idx].set(k.astype(pkv.k.dtype), mode="drop")
+    nv = pkv.v.at[idx].set(v.astype(pkv.v.dtype), mode="drop")
+    return pkv._replace(k=nk, v=nv)
 
 
 def paged_kv_gather(pkv: PagedKV):
-    """Materialize each row's pages: -> (k [B, MB*BS, Hkv, D], v likewise,
-    k_pos [B, MB*BS] logical positions, valid [B, MB*BS] assigned-block
-    mask). Unassigned table entries gather block 0 and are masked off."""
-    _, hkv, bs, d = pkv.k.shape
+    """Materialize each row's pages of layer ``pkv.layer``: -> (k [B,
+    MB*BS, Hkv, D], v likewise, k_pos [B, MB*BS] logical positions, valid
+    [B, MB*BS] assigned-block mask). Unassigned table entries gather block
+    0 and are masked off."""
+    _, _, hkv, bs, d = pkv.k.shape
     b, mb = pkv.tables.shape
     safe = jnp.maximum(pkv.tables, 0)
     # [B, MB, Hkv, BS, D] -> position-major [B, MB * BS, Hkv, D]
-    kg = pkv.k[safe].transpose(0, 1, 3, 2, 4).reshape(b, mb * bs, hkv, d)
-    vg = pkv.v[safe].transpose(0, 1, 3, 2, 4).reshape(b, mb * bs, hkv, d)
+    kg = pkv.k[pkv.layer, safe].transpose(0, 1, 3, 2, 4).reshape(
+        b, mb * bs, hkv, d)
+    vg = pkv.v[pkv.layer, safe].transpose(0, 1, 3, 2, 4).reshape(
+        b, mb * bs, hkv, d)
     k_pos = jnp.broadcast_to(jnp.arange(mb * bs, dtype=jnp.int32)[None],
                              (b, mb * bs))
     valid = jnp.repeat(pkv.tables >= 0, bs, axis=1)
@@ -348,26 +388,28 @@ def paged_decode_attention(cfg, q, k, v, pkv: PagedKV, positions, window,
     [P, C] chunks at per-lane position spans; ``valid`` [B, C] masks padded
     lane positions out of the K/V write — their query rows compute garbage
     that the caller discards). Returns (attn out [B, C, Hq, D],
-    (new_k, new_v) block pools).
+    (new_k, new_v) whole block pools).
 
     ``cfg.use_pallas`` routes single-token decode through the Pallas
     block-table decode kernel and multi-token chunks through the paged
     *prefill* kernel (both in kernels/paged_attention.py — positions of a
-    chunk are contiguous per row, which is what the prefill kernel assumes);
-    the default path gathers pages and reuses ``mha`` so paged outputs stay
+    chunk are contiguous per row, which is what the prefill kernel assumes),
+    each given the layer's [NB, Hkv, BS, D] slab of the written pool; the
+    default path gathers pages and reuses ``mha`` so paged outputs stay
     token-identical to contiguous decode.
     """
     b, c = q.shape[:2]
     pkv = paged_kv_write(pkv, k, v, positions, valid)
-    if cfg.use_pallas and c == 1:
+    if cfg.use_pallas:
         from repro.kernels import ops as kops
-        out = kops.paged_attention(q[:, 0], pkv.k, pkv.v, pkv.tables,
-                                   positions[:, 0], window)[:, None]
-        return out, (pkv.k, pkv.v)
-    if cfg.use_pallas and c > 1:
-        from repro.kernels import ops as kops
-        out = kops.paged_prefill_attention(q, pkv.k, pkv.v, pkv.tables,
-                                           positions[:, 0], window)
+        kl = jax.lax.dynamic_index_in_dim(pkv.k, pkv.layer, keepdims=False)
+        vl = jax.lax.dynamic_index_in_dim(pkv.v, pkv.layer, keepdims=False)
+        if c == 1:
+            out = kops.paged_attention(q[:, 0], kl, vl, pkv.tables,
+                                       positions[:, 0], window)[:, None]
+        else:
+            out = kops.paged_prefill_attention(q, kl, vl, pkv.tables,
+                                               positions[:, 0], window)
         return out, (pkv.k, pkv.v)
     kg, vg, k_pos, assigned = paged_kv_gather(pkv)
     kg = shard(kg, "batch", "kv_seq", None, None)
@@ -438,7 +480,7 @@ def attention(p, cfg, x, positions, *, causal: bool = True,
     """
     b, s, _ = x.shape
     if isinstance(kv_cache, PagedKV):
-        kv_len = kv_cache.tables.shape[1] * kv_cache.k.shape[2]
+        kv_len = kv_cache.kv_len
     else:
         kv_len = (kv_cache[0].shape[1] if kv_cache is not None
                   else cross_kv[0].shape[1] if cross_kv is not None else s)

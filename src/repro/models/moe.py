@@ -237,12 +237,13 @@ def paged_prefill_chunk(cfg, params, cache, tokens, start, tables,
     if state is None:
         state = paged_prefill_state(cfg, b)
 
-    def body(x, scanned):
-        p, ck, cv, cnt = scanned
+    def body(x, pool, layer, scanned):
+        p, cnt = scanned
         h = L.rmsnorm(x, p["norm1"], cfg.norm_eps)
-        attn_out, new_kv = L.attention(p["attn"], cfg, h, positions,
-                                       kv_cache=L.PagedKV(ck, cv, tables),
-                                       kv_valid=valid)
+        attn_out, (k, v) = L.attention(
+            p["attn"], cfg, h, positions,
+            kv_cache=L.PagedKV(pool["k"], pool["v"], tables, layer),
+            kv_valid=valid)
         x = x + attn_out
         h = L.rmsnorm(x, p["norm2"], cfg.norm_eps)
         ffn_out, _aux, new_cnt = moe_ffn(cfg, p, h, counts=cnt,
@@ -250,14 +251,14 @@ def paged_prefill_chunk(cfg, params, cache, tokens, start, tables,
                                          token_valid=valid,
                                          cap_rows=cap_rows)
         x = shard(x + ffn_out, "batch", None, None)
-        return x, (*new_kv, new_cnt)
+        return x, {"k": k, "v": v}, new_cnt
 
-    x, (new_k, new_v, new_counts) = L.scan_layers(
-        cfg, body, x, (params["layers"], cache["k"], cache["v"], state))
+    x, cache, new_counts = L.scan_paged_layers(cfg, body, x, cache,
+                                               (params["layers"], state))
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = L.unembed(params["emb"], cfg, x)
     logits = jnp.take_along_axis(logits, last[:, None, None], axis=1)
-    return logits, {"k": new_k, "v": new_v}, new_counts
+    return logits, cache, new_counts
 
 
 def paged_decode_step(cfg, params, cache, tokens, pos, tables,
@@ -268,18 +269,17 @@ def paged_decode_step(cfg, params, cache, tokens, pos, tables,
     positions = L.decode_positions(b, pos)
     kv_valid = None if write_valid is None else write_valid[:, None]
 
-    def body(x, scanned):
-        p, ck, cv = scanned
-        x, new_kv, _aux = _layer(cfg, p, x, positions,
-                                 kv_cache=L.PagedKV(ck, cv, tables),
-                                 kv_valid=kv_valid)
-        return x, new_kv
+    def body(x, pool, layer, p):
+        x, (k, v), _aux = _layer(
+            cfg, p, x, positions,
+            kv_cache=L.PagedKV(pool["k"], pool["v"], tables, layer),
+            kv_valid=kv_valid)
+        return x, {"k": k, "v": v}, None
 
-    x, (new_k, new_v) = L.scan_layers(
-        cfg, body, x, (params["layers"], cache["k"], cache["v"]))
+    x, cache, _ = L.scan_paged_layers(cfg, body, x, cache, params["layers"])
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = L.unembed(params["emb"], cfg, x)
-    return logits, {"k": new_k, "v": new_v}
+    return logits, cache
 
 
 def decode_step(cfg, params, cache, tokens, pos, write_valid=None):

@@ -71,7 +71,7 @@ def _attention_dyn_window(cfg, p, x, positions, window, kv_cache, cache_pos,
     """Attention with a *traced* window size (for scanned local/global mix)."""
     b, s, _ = x.shape
     if isinstance(kv_cache, L.PagedKV):
-        kv_len = kv_cache.tables.shape[1] * kv_cache.k.shape[2]
+        kv_len = kv_cache.kv_len
     else:
         kv_len = kv_cache[0].shape[1] if kv_cache is not None else s
     scheme = L.plan_attention_scheme(cfg, b, s, kv_len)
@@ -346,19 +346,20 @@ def paged_prefill_chunk(cfg, params, cache, tokens, start, tables,
     positions, valid, last = prefill_chunk_layout(start, n_valid, b, c)
     windows = layer_windows(cfg)
 
-    def body(x, scanned):
-        p, w, ck, cv = scanned
-        x, new_kv = _layer(cfg, p, x, positions, w,
-                           kv_cache=L.PagedKV(ck, cv, tables),
+    def body(x, pool, layer, scanned):
+        p, w = scanned
+        x, (k, v) = _layer(cfg, p, x, positions, w,
+                           kv_cache=L.PagedKV(pool["k"], pool["v"], tables,
+                                              layer),
                            kv_valid=valid)
-        return x, new_kv
+        return x, {"k": k, "v": v}, None
 
-    x, (new_k, new_v) = L.scan_layers(
-        cfg, body, x, (params["layers"], windows, cache["k"], cache["v"]))
+    x, cache, _ = L.scan_paged_layers(cfg, body, x, cache,
+                                      (params["layers"], windows))
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = L.unembed(params["emb"], cfg, x)
     logits = jnp.take_along_axis(logits, last[:, None, None], axis=1)
-    return logits, {"k": new_k, "v": new_v}, None
+    return logits, cache, None
 
 
 def paged_decode_step(cfg, params, cache, tokens, pos, tables,
@@ -374,18 +375,19 @@ def paged_decode_step(cfg, params, cache, tokens, pos, tables,
     windows = layer_windows(cfg)
     kv_valid = None if write_valid is None else write_valid[:, None]
 
-    def body(x, scanned):
-        p, w, ck, cv = scanned
-        x, new_kv = _layer(cfg, p, x, positions, w,
-                           kv_cache=L.PagedKV(ck, cv, tables),
+    def body(x, pool, layer, scanned):
+        p, w = scanned
+        x, (k, v) = _layer(cfg, p, x, positions, w,
+                           kv_cache=L.PagedKV(pool["k"], pool["v"], tables,
+                                              layer),
                            kv_valid=kv_valid)
-        return x, new_kv
+        return x, {"k": k, "v": v}, None
 
-    x, (new_k, new_v) = L.scan_layers(
-        cfg, body, x, (params["layers"], windows, cache["k"], cache["v"]))
+    x, cache, _ = L.scan_paged_layers(cfg, body, x, cache,
+                                      (params["layers"], windows))
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = L.unembed(params["emb"], cfg, x)
-    return logits, {"k": new_k, "v": new_v}
+    return logits, cache
 
 
 def decode_step(cfg, params, cache, tokens, pos, write_valid=None):

@@ -515,11 +515,13 @@ class ServeEngine:
     def _paged_prefill_fn(self):
         """Jitted lane-batched chunk prefill; ``cap`` is static (it sizes
         the MoE dispatch buffers — per-lane effective capacity is the traced
-        ``cap_rows``, so one program covers every prompt length). Its XLA
-        module is ``jit_serve_prefill_round``."""
+        ``cap_rows``, so one program covers every prompt length). The pool
+        ``buffers`` are donated: the returned pool is the same memory,
+        written in place. Its XLA module is ``jit_serve_prefill_round``."""
         mod, cfg = self.model.module, self.cfg
 
-        @functools.partial(jax.jit, static_argnames=("cap",))
+        @functools.partial(jax.jit, static_argnames=("cap",),
+                           donate_argnames=("buffers",))
         def serve_prefill_round(params, buffers, tokens, starts, n_valid,
                                 tables, state, cap_rows, cap):
             return mod.paged_prefill_chunk(cfg, params, buffers, tokens,
@@ -665,22 +667,25 @@ class ServeEngine:
             stop = stop.at[idx].set(s)
             return buffers, tok, pos, stop, blk
 
-        return self._jit_horizon(serve_decode_horizon)
+        return self._jit_horizon(serve_decode_horizon, donate=True)
 
-    def _jit_horizon(self, horizon):
+    def _jit_horizon(self, horizon, donate: bool = False):
         """jit with ``h`` (scan length) and ``full`` (identity bucket —
         no gather/scatter) static; sharded plans pin the cache to its
         NamedSharding and the state arrays to replicated so input
-        shardings stay stable across calls. Both backends name the
-        function ``serve_decode_horizon``: one XLA module name,
+        shardings stay stable across calls. ``donate`` gives the cache
+        ``buffers`` to the program, so the returned pool is written in
+        place (the paged pool; the contiguous horizon's pool is not
+        donated). Both backends name the function
+        ``serve_decode_horizon``: one XLA module name,
         ``jit_serve_decode_horizon``, for every horizon program."""
         plan = self.sharding
+        kw = dict(static_argnames=("h", "full"),
+                  donate_argnames=("buffers",) if donate else ())
         if plan is not None:
             rep = plan.replicated()
-            return jax.jit(horizon, static_argnames=("h", "full"),
-                           out_shardings=(plan.cache_sharding,
-                                          rep, rep, rep, rep))
-        return jax.jit(horizon, static_argnames=("h", "full"))
+            kw["out_shardings"] = (plan.cache_sharding, rep, rep, rep, rep)
+        return jax.jit(horizon, **kw)
 
     # -- the engine loop ---------------------------------------------------------
     def run(self, requests: List[ServeRequest]

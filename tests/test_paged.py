@@ -187,29 +187,154 @@ def test_paged_prefill_ignores_stale_blocks():
                                    atol=1e-6, rtol=1e-6)
 
 
+def test_paged_kv_write_updates_only_its_layer_and_valid_rows():
+    """The carried-pool write puts each valid (row, position) at its layer,
+    block and offset, and touches nothing else: other layers, a -1 table
+    entry, a position past the table, a padded lane position (``valid``
+    False) and every block no row names (a prefix-shared or foreign block)
+    keep their content. Two rows writing other offsets of one block (block
+    4 here) both land."""
+    from repro.models import layers as L
+    n_layers, nb, hkv, bs, d = 3, 6, 2, 4, 8
+    rng = np.random.default_rng(3)
+    pool = rng.normal(size=(n_layers, nb, hkv, bs, d)).astype(np.float32)
+    tables = np.array([[4, 1, -1], [0, 4, -1]], np.int32)
+    positions = np.array([[2, 3, 4, 5], [4, 5, 8, 12]], np.int32)
+    valid = np.array([[True, True, True, False], [True, False, True, True]])
+    k = rng.normal(size=(2, 4, hkv, d)).astype(np.float32)
+    out = L.paged_kv_write(
+        L.PagedKV(jnp.asarray(pool), jnp.asarray(-pool), jnp.asarray(tables),
+                  jnp.int32(1)),
+        jnp.asarray(k), jnp.asarray(-k), jnp.asarray(positions),
+        jnp.asarray(valid))
+    want = pool.copy()
+    for b in range(2):
+        for c in range(4):
+            p = positions[b, c]
+            col = p // bs
+            if valid[b, c] and col < tables.shape[1] and tables[b, col] >= 0:
+                want[1, tables[b, col], :, p % bs] = k[b, c]
+    np.testing.assert_array_equal(np.asarray(out.k), want)
+    np.testing.assert_array_equal(np.asarray(out.v), -want)
+
+
 # ---------------------------------------------------------------------------
 # paged continuous == contiguous static, per request
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("arch", PAGED_ARCHS)
-def test_paged_matches_contiguous_static_per_request(arch):
+def _identity_case(cfg, case):
+    """(cfg, requests(with_arrivals), max_len, paged engine kwargs) of one
+    paged-vs-contiguous identity case. Every case but ``staggered`` runs
+    several decode horizons."""
+    kw = dict(n_slots=3, block_size=4)
+    lengths, arrivals, budgets = [5, 3, 8, 2, 6], [0.0, 0.0, 1.0, 3.0, 4.0], None
+    common = None
+    max_len = 32
+    if case == "prefix":        # later arrivals hit the donor's two blocks
+        rng = np.random.default_rng(21)
+        common = rng.integers(1, cfg.vocab_size, size=8).astype(np.int32)
+        lengths, arrivals = [1, 2, 3, 4], [0.0, 2.0, 4.0, 6.0]
+        budgets, kw = [9] * 4, dict(n_slots=2, block_size=4, decode_horizon=4)
+    elif case == "padded":      # every prompt ends in a padded chunk
+        lengths, arrivals = [7, 10, 13, 6], [0.0] * 4
+        budgets = [9] * 4
+        kw = dict(n_slots=4, block_size=4, prefill_lanes=2, decode_horizon=4)
+    elif case == "frozen":      # rows finish and freeze mid-horizon
+        lengths, arrivals = [6, 4, 9, 5], [0.0] * 4
+        budgets, kw = [2, 11, 5, 8], dict(n_slots=4, block_size=4)
+    elif case == "windowed":    # one local (window 8) and one global layer
+        cfg = cfg.replace(sliding_window=8, global_every=2)
+        lengths, arrivals = [12, 19, 9], [0.0, 0.0, 2.0]
+        budgets, max_len = [10, 10, 12], 48
+        kw = dict(n_slots=2, block_size=4, decode_horizon=4)
+
+    def reqs(with_arrivals):
+        rs = _requests(cfg, lengths, arrivals if with_arrivals else None)
+        for i, r in enumerate(rs):
+            if common is not None:
+                r.prompt = np.concatenate([common, r.prompt])
+            if budgets is not None:
+                r.max_new_tokens = budgets[i]
+        return rs
+    return cfg, reqs, max_len, kw
+
+
+IDENTITY_CASES = (
+    [pytest.param(a, "staggered", id=a) for a in PAGED_ARCHS]
+    + [pytest.param(a, c, id=f"{a}-{c}")
+       for a in ("llama3.2-1b", "olmoe-1b-7b")
+       for c in ("prefix", "padded", "frozen")]
+    + [pytest.param("gemma3-27b", "windowed", id="gemma3-27b-windowed")])
+
+
+@pytest.mark.parametrize("arch,case", IDENTITY_CASES)
+def test_paged_matches_contiguous_static_per_request(arch, case):
     """Mixed lengths, staggered arrivals, block reuse — paged continuous
     outputs must be token-for-token identical to one contiguous static batch
-    (the acceptance invariant, also checked by launch.serve --verify)."""
-    cfg = get_config(arch, smoke=True)
-    lengths, arrivals = [5, 3, 8, 2, 6], [0.0, 0.0, 1.0, 3.0, 4.0]
+    (the acceptance invariant, also checked by launch.serve --verify).
+    Further cases: prefix-cache hits, padded final prefill chunks, rows
+    frozen mid-horizon, a sliding-window config, on dense and MoE."""
+    cfg, reqs, max_len, kw = _identity_case(get_config(arch, smoke=True),
+                                            case)
+    params = build_model(cfg).init(jax.random.key(0))
 
-    static, _ = ServeEngine(cfg, max_len=32).run(_requests(cfg, lengths))
-    paged, stats = ServeEngine(cfg, max_len=32, n_slots=3, cache="paged",
-                               block_size=4).run(
-        _requests(cfg, lengths, arrivals))
+    static, _ = ServeEngine(cfg, params=params, max_len=max_len).run(
+        reqs(False))
+    paged, stats = ServeEngine(cfg, params=params, max_len=max_len,
+                               cache="paged", **kw).run(reqs(True))
 
     for a, b in zip(static, paged):
         assert a.output == b.output
     assert all(r.finished_at is not None for r in paged)
-    # idle-slot compaction: the paged engine decoded fewer rows than
-    # steps * n_slots would have
-    assert stats.decode_rows_saved > 0.0
     assert stats.block_report["block_size"] == 4
+    if case == "staggered":
+        # idle-slot compaction: the paged engine decoded fewer rows than
+        # steps * n_slots would have
+        assert stats.decode_rows_saved > 0.0
+    else:
+        assert stats.decode_dispatches > 1
+    if case == "prefix":
+        assert stats.prefix_blocks_hit > 0
+    if case == "frozen":
+        assert stats.decode_dispatches < stats.steps
+
+
+def test_paged_programs_donate_the_pool():
+    """The paged prefill round and decode horizon are given the K/V pool
+    to keep: every pool a dispatch was handed is deleted after it, and the
+    compiled program aliases at least the pool's bytes to its output. The
+    contiguous horizon donates nothing."""
+    cfg = get_config("llama3.2-1b", smoke=True)
+    params = build_model(cfg).init(jax.random.key(0))
+    struct = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
+    for cache, names in (("paged", ("_prefill", "_horizon")),
+                         ("contiguous", ("_horizon",))):
+        eng = ServeEngine(cfg, params=params, max_len=32, n_slots=2,
+                          cache=cache, block_size=4)
+        seen, jitted = {}, {}
+        for name in names:
+            jitted[name] = getattr(eng, name)
+
+            def record(params, buffers, *a, _fn=jitted[name],
+                       _log=seen.setdefault(name, []), **kw):
+                _log.append((buffers, a, kw))
+                return _fn(params, buffers, *a, **kw)
+            setattr(eng, name, record)
+        eng.run(_requests(cfg, [5, 6], max_new=6))
+        donated = cache == "paged"
+        for name in names:
+            assert seen[name], name
+            for buffers, _, _ in seen[name]:
+                assert all(leaf.is_deleted() == donated
+                           for leaf in jax.tree_util.tree_leaves(buffers))
+            buffers, a, kw = seen[name][0]
+            pool_bytes = sum(leaf.nbytes
+                             for leaf in jax.tree_util.tree_leaves(buffers))
+            alias = jitted[name].lower(params, struct(buffers), *struct(a),
+                                       **kw).compile().memory_analysis() \
+                .alias_size_in_bytes
+            assert (alias >= pool_bytes) if donated else alias == 0, \
+                (cache, name, alias, pool_bytes)
 
 
 def test_recorded_prompt_logits_match_across_backends():
